@@ -37,7 +37,7 @@ import time
 
 from benchmarks.common import bench_envelope, fmt_row, geomean
 from repro.core import build_bvss, reference_bfs
-from repro.core.autotune import stats as autotune_stats
+from repro import tracing
 from repro.core.autotune import tune
 from repro.core.bfs import INF, BlestProblem, make_blest_bfs, queue_widths
 from repro.core.policy import parents_from_levels
@@ -163,7 +163,8 @@ def run(scale: int = 14, n_sources: int = 2, reps: int = 5,
              if go["timed"]]),
         "all_verified": all(all(go["verified"].values())
                             for go in graphs_out.values()),
-        "autotune": dict(autotune_stats),
+        "autotune": {k: tracing.counter("autotune." + k)
+                     for k in ("tune_runs", "cache_hits")},
     }
     out = {
         **bench_envelope("pr7_hybrid_compiled_dispatch", scale),

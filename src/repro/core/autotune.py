@@ -14,7 +14,8 @@ once per *(backend, σ, size-class)* and memoises the winner:
 * the module-level cache keys on ``(backend, σ, pow2-bucketized num_vss,
   use_kernels)``: a second ``prepare(..., autotune=True)`` for the same
   backend and graph class performs ZERO additional timing dispatches (the
-  ``stats`` counters make that contract testable);
+  tracer's ``autotune.tune_runs`` and ``autotune.cache_hits`` counters
+  make that contract testable);
 * ``BLEST_AUTOTUNE=0`` in the environment disables measurement entirely
   (the default config is returned, marked ``source="disabled"``) — the CI
   escape hatch for timing-hostile runners.
@@ -34,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.bfs import (DEFAULT_PUSH_CAP, _round_width, queue_widths)
 from repro.errors import ConfigError
 
@@ -66,8 +68,6 @@ class TileConfig:
 
 #: (backend, sigma, pow2 size class, use_kernels) -> winning TileConfig
 _TUNE_CACHE: dict[tuple, TileConfig] = {}
-#: observable tuning activity — the zero-retune contract's test surface
-stats = {"tune_runs": 0, "cache_hits": 0}
 
 
 def clear_cache() -> None:
@@ -173,11 +173,11 @@ def tune(problem, *, use_kernels: bool = True,
     key = class_key(problem, use_kernels)
     cached = _TUNE_CACHE.get(key)
     if cached is not None:
-        stats["cache_hits"] += 1
+        tracing.count("autotune.cache_hits")
         return dataclasses.replace(cached, source="cached")
     if os.environ.get("BLEST_AUTOTUNE", "") == "0":
         return default_config(problem)
-    stats["tune_runs"] += 1
+    tracing.count("autotune.tune_runs")
     sigma = problem.sigma
     if use_kernels:
         from repro.kernels import pull_vss_kernel, push_vss_kernel

@@ -244,6 +244,36 @@ class _BlestState(NamedTuple):
                             #   Beamer-style saturation guard of the
                             #   direction heuristic, DESIGN §2.8)
     cont: jnp.ndarray    # bool continue flag (mesh-global via psum)
+    tally: jnp.ndarray | None = None  # level counters after ``levels``
+                                      #   (LEVEL_COUNTERS); None = uncounted
+
+
+#: the counted engine's device counters, in the order it returns them,
+#: then ``pull_levels.<i>`` for each ladder rung (narrowest first):
+#: ``levels`` loop iterations, ``push_levels``, ``pull_slots`` the sum of
+#: the pull widths chosen and ``live_vss`` the sum of the live VSS count,
+#: both over pull levels
+LEVEL_COUNTERS = ("levels", "push_levels", "pull_slots", "live_vss")
+
+
+def level_counter_names(n: int) -> tuple[str, ...]:
+    """Names of a counted engine's ``n`` counters."""
+    rungs = n - len(LEVEL_COUNTERS)
+    return LEVEL_COUNTERS + tuple(f"pull_levels.{i}" for i in range(rungs))
+
+
+def _tally(tally: jnp.ndarray | None, use_push, widths: list[int], count
+           ) -> jnp.ndarray | None:
+    """Add one level to the counters after ``levels`` (the loop counts its
+    own iterations): push or pull, and a pull level's width and rung."""
+    if tally is None:
+        return None
+    pull = 1 - jnp.asarray(use_push, dtype=jnp.int32)
+    rung = sum((count > w).astype(jnp.int32) for w in widths[:-1])
+    return tally + jnp.concatenate([
+        jnp.stack([1 - pull, pull * selected_width(widths, count),
+                   pull * count]),
+        pull * jax.nn.one_hot(rung, len(widths), dtype=jnp.int32)])
 
 
 def _round_width(x: int) -> int:
@@ -414,6 +444,7 @@ def _make_hybrid_step(dev, pull: PullFn, push: PushFn | None, sigma: int,
         raise ConfigError(
             f"direction must be one of {DIRECTIONS}, got {direction!r}")
 
+    @jax.named_scope("bfs.update")
     def scatter(state: _BlestState, rows, h, lvl) -> _BlestState:
         if lazy:
             # Alg. 3 stage 1: fire-and-forget mark (REDG analogue)
@@ -435,8 +466,11 @@ def _make_hybrid_step(dev, pull: PullFn, push: PushFn | None, sigma: int,
                        hits.reshape(-1), lvl)
 
     def pull_step(state: _BlestState, lvl) -> _BlestState:
-        return select_width(widths, state.count,
-                            lambda w: pull_update(state, lvl, w))
+        with jax.named_scope("bfs.expand"):
+            out = select_width(widths, state.count,
+                               lambda w: pull_update(state, lvl, w))
+        return out._replace(
+            tally=_tally(state.tally, False, widths, state.count))
 
     if direction == "pull":
         return pull_step
@@ -449,26 +483,31 @@ def _make_hybrid_step(dev, pull: PullFn, push: PushFn | None, sigma: int,
         return pull_step
     compact_vertices = make_vertex_compactor(n_fwords, dummy_vertex, pqcap)
 
-    def push_update(state: _BlestState, lvl) -> _BlestState:
-        VQ, _ = compact_vertices(state.F)
-        ids = expand_push_queue(dev, VQ, R, num_vss)
-        bits = jnp.broadcast_to((VQ % sigma).astype(jnp.int32)[:, None],
-                                (pqcap, R)).reshape(-1)
-        hits = push(dev.masks[ids], bits, sigma)         # (pqcap*R, spw, 32)
-        return scatter(state, dev.row_ids[ids].reshape(-1),
-                       hits.reshape(-1), lvl)
+    def push_step(state: _BlestState, lvl) -> _BlestState:
+        with jax.named_scope("bfs.expand"):
+            VQ, _ = compact_vertices(state.F)
+            ids = expand_push_queue(dev, VQ, R, num_vss)
+            bits = jnp.broadcast_to((VQ % sigma).astype(jnp.int32)[:, None],
+                                    (pqcap, R)).reshape(-1)
+            hits = push(dev.masks[ids], bits, sigma)     # (pqcap*R, spw, 32)
+            out = scatter(state, dev.row_ids[ids].reshape(-1),
+                          hits.reshape(-1), lvl)
+        return out._replace(
+            tally=_tally(state.tally, True, widths, state.count))
 
     if direction == "push":
-        return push_update
+        return push_step
 
     def step(state: _BlestState, lvl) -> _BlestState:
-        fcount = jnp.sum(jax.lax.population_count(state.F)).astype(jnp.int32)
-        use_push = ((fcount <= push_cap)
-                    & (jnp.int32(push_cost)
-                       < selected_width(widths, state.count))
-                    & (fcount * jnp.float32(alpha)
-                       <= state.unvisited.astype(jnp.float32)))
-        return jax.lax.cond(use_push, push_update, pull_step, state, lvl)
+        with jax.named_scope("bfs.expand"):
+            fcount = jnp.sum(jax.lax.population_count(state.F)
+                             ).astype(jnp.int32)
+            use_push = ((fcount <= push_cap)
+                        & (jnp.int32(push_cost)
+                           < selected_width(widths, state.count))
+                        & (fcount * jnp.float32(alpha)
+                           <= state.unvisited.astype(jnp.float32)))
+        return jax.lax.cond(use_push, push_step, pull_step, state, lvl)
 
     return step
 
@@ -488,8 +527,8 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
                    use_kernels: bool = True, buckets: int = 2,
                    widths: list[int] | None = None,
                    direction: str = "auto", push_cap: int | None = None,
-                   alpha: float = 4.0, max_levels: int | None = None
-                   ) -> Callable:
+                   alpha: float = 4.0, max_levels: int | None = None,
+                   counters: bool = False) -> Callable:
     """Build the jitted fused BLEST BFS (Alg. 2 eager / Alg. 3 lazy).
 
     The level step is direction-optimizing (DESIGN §2.8): the pull side is
@@ -518,6 +557,15 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
                  push always uses the full vertex count).
     alpha:       Beamer-style saturation guard: auto only pushes while
                  ``alpha * popcount(F) <= unvisited``.
+    counters:    return ``(levels, counts)``: the level loop's int32
+                 counters (:func:`level_counter_names`); single-device
+                 only.
+
+    The level step runs under three named scopes, which reach the
+    compiled HLO's ``op_name``: ``bfs.expand`` (frontier gather and pull,
+    or push compaction and expansion, kernels included), ``bfs.update``
+    (scatter-min or lazy marks) and ``bfs.finalize`` (the finalise /
+    pack / flag sweep and the queue compaction).
     """
     p = problem
     sigma = p.sigma
@@ -545,6 +593,9 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
     fin_impl = finalize_pack_sweep if use_kernels else finalize_pack_ref
 
     if p.mesh is not None:
+        if counters:
+            raise ConfigError("level counters exist only on the "
+                              "single-device engine")
         if p.is_2d:
             return _make_blest_bfs_sharded_2d(p, pull=pull, widths=widths,
                                               qcap=qcap, max_lv=max_lv,
@@ -564,6 +615,7 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
                              n_fwords=p.n_fwords, dummy_vertex=p.n,
                              R=p.max_vss_per_set, push_cap=cap, alpha=alpha)
 
+    @jax.named_scope("bfs.finalize")
     def finalize(state: _BlestState, lvl) -> _BlestState:
         if lazy:
             # Alg. 3 stage 2 fused: finalise + pack + set flags in one sweep
@@ -591,9 +643,17 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
         set0 = jnp.zeros((p.n_sets,), dtype=bool).at[src // sigma].set(True)
         Q, count = compact(set0)
         marks0 = jnp.zeros((p.n + 1 if lazy else 1,), dtype=jnp.uint8)
+        tally = jnp.zeros((len(LEVEL_COUNTERS) - 1 + len(widths),),
+                          dtype=jnp.int32) if counters else None
         state = _BlestState(levels, F, Q, count, marks0,
-                            jnp.int32(p.n - 1), count > 0)
-        state, _ = run_levels(pipe, state, max_levels=max_lv)
+                            jnp.int32(p.n - 1), count > 0, tally)
+        # the loop's own control (level count, continuation test, the
+        # counters) is charged to finalize; the step's scopes nest inside
+        with jax.named_scope("bfs.finalize"):
+            state, n_levels = run_levels(pipe, state, max_levels=max_lv)
+        if counters:
+            return state.levels[:p.n], jnp.concatenate(
+                [n_levels[None], state.tally])
         return state.levels[:p.n]
 
     return jax.jit(bfs)
@@ -641,6 +701,7 @@ def _make_blest_bfs_sharded(p: BlestProblem, *, lazy: bool, pull: PullFn,
                                  push_cap=push_cap, alpha=alpha)
         d = jax.lax.axis_index(axis)
 
+        @jax.named_scope("bfs.finalize")
         def finalize(state: _BlestState, lvl) -> _BlestState:
             # local fused sweep over THIS shard's rows; its local set flags
             # are meaningless (sets are global) and discarded
@@ -1050,7 +1111,8 @@ def make_engine(g: Graph, engine: str, *, sigma: int = 8,
                 direction: str = "auto", push_cap: int | None = None,
                 alpha: float = 4.0,
                 n_sources: int | None = None,
-                block: int | None = None) -> Callable:
+                block: int | None = None,
+                counters: bool = False) -> Callable:
     """Build a jitted BFS callable ``f(src) -> levels`` for the named engine.
 
     ``problem`` lets callers that already hold a :class:`BlestProblem`
@@ -1060,6 +1122,8 @@ def make_engine(g: Graph, engine: str, *, sigma: int = 8,
     ``direction``/``push_cap``/``alpha``/``widths`` are the hybrid knobs
     (DESIGN §2.8) of the blest/blest_lazy/multi_source engines;
     ``push_impl`` is the push-kernel fault seam.
+    ``counters=True`` makes the single-device blest/blest_lazy engines
+    return ``(levels, counts)`` (:func:`make_blest_bfs`).
     ``engine="multi_source"`` builds the batched BVSS bit-SpMM engine
     ``f(sources (S,)) -> levels (n, S)`` and requires ``n_sources``.
     ``block`` is accepted for backwards compatibility and ignored: the fused
@@ -1067,6 +1131,8 @@ def make_engine(g: Graph, engine: str, *, sigma: int = 8,
     sequential blocks.
     """
     del block
+    if counters and engine not in ("blest", "blest_lazy"):
+        raise ConfigError(f"engine {engine!r} keeps no level counters")
     if engine == "dense_pull":
         return make_dense_pull_bfs(g)
     if engine in ("csr_push", "csr_pull", "dirop"):
@@ -1092,7 +1158,8 @@ def make_engine(g: Graph, engine: str, *, sigma: int = 8,
                               pull_impl=pull_impl, push_impl=push_impl,
                               use_kernels=use_kernels, buckets=buckets,
                               widths=widths, direction=direction,
-                              push_cap=push_cap, alpha=alpha)
+                              push_cap=push_cap, alpha=alpha,
+                              counters=counters)
     raise ValueError(f"unknown engine {engine!r}")
 
 

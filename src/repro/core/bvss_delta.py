@@ -47,7 +47,7 @@ import numpy as np
 from repro.core.bfs import BlestProblem
 from repro.core.bvss import BVSS, LANES
 from repro.core.policy import (BVSS_ENGINES, PreparedBFS, PrepareOptions,
-                               prepare)
+                               build_engine, prepare)
 from repro.errors import (ConfigError, GraphValidationError, StaleEpochError,
                           check_weights)
 from repro.graphs import Graph, from_edges, src_of_edges
@@ -556,14 +556,10 @@ def _finish(prepared: PreparedBFS, g_ord2: Graph, bvss2: BVSS, problem2,
     result.  The engine rebuild recompiles (device arrays are closure
     constants of the jitted level loop) — the accepted cost of an epoch
     swap, amortised by batching updates (DESIGN §2.10)."""
-    from repro.core.bfs import make_engine
-
     tuned = prepared.tile_config.engine_kwargs() \
         if prepared.tile_config is not None else {}
-    fn = make_engine(g_ord2, prepared.engine_name, bvss=bvss2,
-                     problem=problem2, use_kernels=opts.use_kernels,
-                     buckets=opts.buckets, direction=opts.direction,
-                     push_impl=opts.push_impl, **tuned)
+    fn = build_engine(g_ord2, prepared.engine_name, bvss2, problem2, opts,
+                      tuned)
     return dataclasses.replace(
         prepared, graph=g_ord2, bvss=bvss2, problem=problem2,
         update_divergence=bvss2.update_divergence(), weights=w_new,

@@ -24,11 +24,14 @@ import dataclasses
 import warnings
 from typing import Callable
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import tracing
 from repro.core.autotune import TileConfig
-from repro.core.bfs import BlestProblem, make_engine
+from repro.core.bfs import BlestProblem, level_counter_names, make_engine
 from repro.core.bvss import (BVSS, build_bvss, build_sharded_bvss,
                              build_sharded_weight_plane, build_weight_plane,
                              weight_plane_to_device)
@@ -116,14 +119,39 @@ class PreparedBFS:
     # epoch (None on a fresh preparation)
     last_update: "object | None" = None
     _fn: Callable | None = dataclasses.field(default=None)
+    # ``_fn`` compiled on the first query; the calls run this executable,
+    # whose device operations the tracer maps to their stages
+    _compiled: Callable | None = dataclasses.field(default=None, init=False,
+                                                   repr=False)
+
+    def _engine(self) -> Callable:
+        if self._compiled is None:
+            self._compiled = self._fn.lower(
+                jax.ShapeDtypeStruct((), jnp.int32)).compile()
+            tracing.map_stages(self._compiled.as_text())
+        return self._compiled
 
     def levels(self, src: int) -> np.ndarray:
-        """BFS levels in the caller's (original) vertex ids."""
+        """BFS levels in the caller's (original) vertex ids.  A counted
+        engine's level counters go to the tracer, as ``bfs.<name>``,
+        against this query."""
         if self._fn is None:
             raise BlestError("PreparedBFS built without an engine")
-        src = check_source(src, self.graph.n)
-        lv = np.asarray(self._fn(int(self.perm[src])))
-        return lv[self.perm]
+        with tracing.query():
+            src = check_source(src, self.graph.n)
+            with tracing.span("repro.query.dispatch"):
+                out = self._engine()(np.int32(self.perm[src]))
+            with tracing.span("repro.query.wait"):
+                out = jax.block_until_ready(out)
+            with tracing.span("repro.query.copy"):
+                out = jax.device_get(out)
+            if isinstance(out, tuple):
+                out, counts = out
+                tracing.record(dict(zip(
+                    ("bfs." + k for k in level_counter_names(len(counts))),
+                    counts.tolist())))
+            with tracing.span("repro.query.unpermute"):
+                return out[self.perm]
 
 
 def choose_update_scheme(bvss: BVSS, *, threshold: float | None = None
@@ -231,6 +259,30 @@ def prepare(g: Graph, options: PrepareOptions | None = None,
     elif options is None:
         options = PrepareOptions()
     o = options
+    with tracing.span("repro.prepare"):
+        with tracing.span("repro.prepare.ordering"):
+            perm, kind, g_ord, inv, w_ord = _order(g, o)
+        with tracing.span("repro.prepare.bvss"):
+            bvss, engine_name, problem, wplane = _build_bvss(g_ord, w_ord, o)
+        with tracing.span("repro.prepare.engine"):
+            tile_config: TileConfig | None = None
+            tuned_kwargs: dict = {}
+            if o.autotune and engine_name in BVSS_ENGINES \
+                    and problem is not None:
+                from repro.core.autotune import tune
+                tile_config = tune(problem, use_kernels=o.use_kernels)
+                tuned_kwargs = tile_config.engine_kwargs()
+            fn = build_engine(g_ord, engine_name, bvss, problem, o,
+                              tuned_kwargs)
+    return PreparedBFS(graph=g_ord, perm=perm, inv=inv, ordering=kind,
+                       engine_name=engine_name, bvss=bvss, problem=problem,
+                       update_divergence=bvss.update_divergence(),
+                       mesh=o.mesh, weights=w_ord, wplane=wplane,
+                       tile_config=tile_config, options=o, _fn=fn)
+
+
+def _order(g: Graph, o: PrepareOptions):
+    """The ordering stage: ``(perm, kind, g_ord, inv, w_ord)``."""
     w_arr = None if o.weights is None else check_weights(o.weights, g.m)
     if o.order:
         perm, kind = auto_order(g, sigma=o.sigma, w=o.w, seed=o.seed)
@@ -252,6 +304,12 @@ def prepare(g: Graph, options: PrepareOptions | None = None,
             w_ord = w_arr[np.argsort(keys, kind="stable")]
         else:
             w_ord = w_arr
+    return perm, kind, g_ord, inv, w_ord
+
+
+def _build_bvss(g_ord: Graph, w_ord, o: PrepareOptions):
+    """The BVSS stage: the host BVSS, the update scheme and the device
+    problem and weight plane, ``(bvss, engine_name, problem, wplane)``."""
     bvss = build_bvss(g_ord, sigma=o.sigma)
     engine_name = o.engine if o.engine is not None else \
         choose_update_scheme(bvss, threshold=o.lazy_threshold)
@@ -288,21 +346,18 @@ def prepare(g: Graph, options: PrepareOptions | None = None,
         if w_ord is not None:
             wplane = weight_plane_to_device(
                 build_weight_plane(g_ord, w_ord, sigma=o.sigma))
-    tile_config: TileConfig | None = None
-    tuned_kwargs: dict = {}
-    if o.autotune and engine_name in BVSS_ENGINES and problem is not None:
-        from repro.core.autotune import tune
-        tile_config = tune(problem, use_kernels=o.use_kernels)
-        tuned_kwargs = tile_config.engine_kwargs()
-    fn = make_engine(g_ord, engine_name, bvss=bvss, problem=problem,
-                     use_kernels=o.use_kernels, buckets=o.buckets,
-                     direction=o.direction, push_impl=o.push_impl,
-                     **tuned_kwargs)
-    return PreparedBFS(graph=g_ord, perm=perm, inv=inv, ordering=kind,
-                       engine_name=engine_name, bvss=bvss, problem=problem,
-                       update_divergence=bvss.update_divergence(),
-                       mesh=o.mesh, weights=w_ord, wplane=wplane,
-                       tile_config=tile_config, options=o, _fn=fn)
+    return bvss, engine_name, problem, wplane
+
+
+def build_engine(g_ord: Graph, engine_name: str, bvss: BVSS, problem,
+                 o: PrepareOptions, tuned_kwargs: dict) -> Callable:
+    """The prepared single-source engine; the single-device blest and
+    blest_lazy engines also return their level counters."""
+    counted = engine_name in ("blest", "blest_lazy") and o.mesh is None
+    return make_engine(g_ord, engine_name, bvss=bvss, problem=problem,
+                       use_kernels=o.use_kernels, buckets=o.buckets,
+                       direction=o.direction, push_impl=o.push_impl,
+                       counters=counted, **tuned_kwargs)
 
 
 def parents_from_levels(g: Graph, levels: np.ndarray) -> np.ndarray:

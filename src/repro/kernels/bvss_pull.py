@@ -97,6 +97,7 @@ def bvss_pull(masks: jnp.ndarray, fbytes: jnp.ndarray, *, sigma: int = 8,
             out_specs=pl.BlockSpec((spw * 32, tile), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((spw * 32, Bp), jnp.int8),
             interpret=interpret,
+            name="bvss_pull_lanes",
         )(masks_t, fb)
         hits = out.T                             # (Bp, spw*32), k = j*32+l
     elif layout == "rows":
@@ -111,6 +112,7 @@ def bvss_pull(masks: jnp.ndarray, fbytes: jnp.ndarray, *, sigma: int = 8,
             out_specs=pl.BlockSpec((tile, spw * 32), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((Bp, spw * 32), jnp.int8),
             interpret=interpret,
+            name="bvss_pull_rows",
         )(masks, fb)
         hits = out
     else:

@@ -83,6 +83,7 @@ def bvss_push(masks: jnp.ndarray, bits: jnp.ndarray, *, sigma: int = 8,
         out_specs=pl.BlockSpec((spw * 32, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((spw * 32, Bp), jnp.int8),
         interpret=interpret,
+        name="bvss_push",
     )(masks.T, bits[None, :])
     hits = out.T[:B].reshape(B, spw, 32)
     return hits.astype(bool)

@@ -104,6 +104,7 @@ def finalize_sweep(marks: jnp.ndarray, levels: jnp.ndarray, lvl: jnp.ndarray,
             jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
         ],
         interpret=interpret,
+        name="finalize_sweep",
     )(_rows(marks, rows, 0), _rows(levels, rows, 0), _smem_scalar(lvl))
     return (levels_out.reshape(-1)[:N],
             new.reshape(-1)[:N].astype(bool))
@@ -186,6 +187,7 @@ def finalize_pack_sweep(levels: jnp.ndarray, lvl: jnp.ndarray, *,
             out_specs=pack_specs,
             out_shape=pack_shape,
             interpret=interpret,
+            name="finalize_pack_eager",
         )(levels_p, _smem_scalar(lvl))
         lv_out = levels  # untouched by eager finalise: no dense write
     else:
@@ -197,6 +199,7 @@ def finalize_pack_sweep(levels: jnp.ndarray, lvl: jnp.ndarray, *,
             out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.int32)]
             + pack_shape,
             interpret=interpret,
+            name="finalize_pack_lazy",
         )(_rows(marks, rows, 0), levels_p, _smem_scalar(lvl))
         lv_out = lv_full.reshape(-1)[:N]
     fwords = fwords.T.reshape(-1)[:n_fwords]

@@ -127,6 +127,7 @@ def bit_spmm(a_packed: jnp.ndarray, x: jnp.ndarray, *,
         out_specs=pl.BlockSpec((TILE_R, TILE_S), lambda r, s, k: (r, s)),
         out_shape=jax.ShapeDtypeStruct((Rp, Sp), jnp.int32),
         interpret=interpret,
+        name="bit_spmm",
     )(a_packed, xp)
     return y[:R, :S]
 
@@ -224,6 +225,7 @@ def bvss_spmm(masks: jnp.ndarray, fbytes: jnp.ndarray, *, sigma: int = 8,
                                lambda b, s: (b, 0, s)),
         out_shape=jax.ShapeDtypeStruct((Bp, spw * 32, Sp), jnp.int32),
         interpret=interpret,
+        name="bvss_spmm",
     )(masks, fbytes)
     return y[:B, :, :S].reshape(B, spw, 32, S)
 
@@ -252,7 +254,7 @@ def _bvss_spmm_t_kernel(masks_ref, hv_ref, y_ref, *, sigma: int):
 
 
 def _spmm_float_call(kernel, masks, vals, mid: int, out_mid: int, *,
-                     sigma: int, tile_b: int | None,
+                     name: str, sigma: int, tile_b: int | None,
                      tile_s: int | None, interpret: bool | None):
     """Shared pallas_call plumbing for the two weighted tile products:
     vals is (B, mid, S) float32, the result (B, out_mid, S) float32."""
@@ -282,6 +284,7 @@ def _spmm_float_call(kernel, masks, vals, mid: int, out_mid: int, *,
                                lambda b, s: (b, 0, s)),
         out_shape=jax.ShapeDtypeStruct((Bp, out_mid, Sp), jnp.float32),
         interpret=interpret,
+        name=name,
     )(masks, vals)
     return y[:B, :, :S]
 
@@ -304,7 +307,8 @@ def bvss_spmm_w(masks: jnp.ndarray, xvals: jnp.ndarray, *, sigma: int = 8,
     spw = 32 // sigma
     B = masks.shape[0]
     y = _spmm_float_call(_bvss_spmm_w_kernel, masks, xvals, sigma, spw * 32,
-                         sigma=sigma, tile_b=tile_b, tile_s=tile_s,
+                         name="bvss_spmm_w", sigma=sigma, tile_b=tile_b,
+                         tile_s=tile_s,
                          interpret=interpret)
     return y.reshape(B, spw, 32, y.shape[2])
 
@@ -326,7 +330,8 @@ def bvss_spmm_t(masks: jnp.ndarray, hvals: jnp.ndarray, *, sigma: int = 8,
     B, spw = hvals.shape[0], hvals.shape[1]
     hv = hvals.reshape(B, spw * 32, hvals.shape[3])
     return _spmm_float_call(_bvss_spmm_t_kernel, masks, hv, spw * 32, sigma,
-                            sigma=sigma, tile_b=tile_b, tile_s=tile_s,
+                            name="bvss_spmm_t", sigma=sigma, tile_b=tile_b,
+                            tile_s=tile_s,
                             interpret=interpret)
 
 
@@ -420,6 +425,7 @@ def _spmm_minplus_call(masks, wvals, xvals, *, sigma: int,
                                lambda b, s: (b, 0, s)),
         out_shape=jax.ShapeDtypeStruct((Bp, tau, Sp), jnp.float32),
         interpret=interpret,
+        name="bvss_spmm_minplus",
     )(masks, wvals, xvals)
     return y[:B, :, :S]
 

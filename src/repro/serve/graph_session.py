@@ -43,7 +43,6 @@ anywhere.
 """
 from __future__ import annotations
 
-import time
 import warnings
 from collections import deque
 from typing import Sequence
@@ -51,6 +50,7 @@ from typing import Sequence
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import tracing
 from repro.analytics import (ExtremesReport, betweenness_centrality,
                              closeness_centrality, connected_components,
                              eccentricities, ifub_extremes, make_pagerank,
@@ -100,7 +100,6 @@ class GraphSession:
                  max_steps: int | None = None, mesh: Mesh | None = None,
                  mesh_axis: str = "data", weights=None,
                  fault_plan: FaultPlan | None = None):
-        t0 = time.time()
         if options is None:
             options = PrepareOptions(
                 sigma=sigma, w=w, seed=seed, lazy_threshold=lazy_threshold,
@@ -131,7 +130,7 @@ class GraphSession:
         self._mesh_axis = options.mesh_axis
         self.max_steps = max_steps
         self._bind_prepared()
-        self.preprocess_s = time.time() - t0
+        self.preprocess_s = tracing.last("repro.prepare").seconds
 
     def _bind_prepared(self) -> None:
         """(Re)build everything derived from ``self.prepared`` — called at

@@ -36,20 +36,24 @@ does not have:
   ``closeness_ref`` the same way.
 
 Every admission decision, eviction, timeout, quarantine and degradation
-is appended to ``manager.events`` (structured dicts) and aggregated by
-``manager.stats()``.
+is appended to ``manager.events`` (structured dicts, the last
+:data:`EVENTS_KEPT`), counted by kind as it arrives, and aggregated by
+``manager.stats()``.  Each level query is traced (``repro.tracing``): a
+``repro.query`` span with its ``repro.query.admit`` and, when sampled,
+``repro.query.verify`` children.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
 import warnings
-from collections import OrderedDict
+from collections import Counter, OrderedDict, deque
 from typing import Any, Callable, Sequence
 
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import tracing
 from repro.core import reference_bfs
 from repro.errors import (AdmissionError, DeadlineExceeded,
                           KernelFaultError, check_sources)
@@ -58,6 +62,8 @@ from repro.kernels.ref import betweenness_ref, closeness_ref
 from repro.serve.graph_session import GraphSession
 
 INF = np.int32(np.iinfo(np.int32).max)
+#: the most recent events a manager keeps (its counts cover all of them)
+EVENTS_KEPT = 4096
 
 
 class DegradedServiceWarning(UserWarning):
@@ -152,13 +158,15 @@ class GraphSessionManager:
         self._clock = clock
         self._sessions: OrderedDict[str, _SessionRecord] = OrderedDict()
         self._quotas: dict[str, TenantQuota] = {}
-        self.events: list[dict[str, Any]] = []
+        self.events: deque[dict[str, Any]] = deque(maxlen=EVENTS_KEPT)
+        self._event_counts: Counter[str] = Counter()
 
     # ------------------------------------------------------------------
     # bookkeeping
     # ------------------------------------------------------------------
     def _event(self, kind: str, **fields) -> None:
         self.events.append({"kind": kind, **fields})
+        self._event_counts[kind] += 1
 
     def quota_for(self, tenant: str) -> TenantQuota:
         return self._quotas.get(tenant, self.default_quota)
@@ -319,17 +327,25 @@ class GraphSessionManager:
             raise ValueError(
                 f"on_deadline must be 'partial' or 'raise', "
                 f"got {on_deadline!r}")
-        rec = self._get(name, tenant)
-        srcs = check_sources(sources, rec.session.n)
-        quota = self.quota_for(tenant)
-        if (quota.max_inflight is not None
-                and len(srcs) > quota.max_inflight):
-            self._event("admission-reject", tenant=tenant, name=name,
-                        reason="inflight")
-            raise AdmissionError(
-                f"{len(srcs)} sources exceed tenant {tenant!r}'s "
-                f"in-flight cap of {quota.max_inflight}",
-                reason="inflight")
+        with tracing.query():
+            return self._levels_batch(name, sources, tenant, deadline_s,
+                                      on_deadline)
+
+    def _levels_batch(self, name: str, sources: Sequence[int], tenant: str,
+                      deadline_s: float | None, on_deadline: str
+                      ) -> list[np.ndarray | TimeoutResult]:
+        with tracing.span("repro.query.admit"):
+            rec = self._get(name, tenant)
+            srcs = check_sources(sources, rec.session.n)
+            quota = self.quota_for(tenant)
+            if (quota.max_inflight is not None
+                    and len(srcs) > quota.max_inflight):
+                self._event("admission-reject", tenant=tenant, name=name,
+                            reason="inflight")
+                raise AdmissionError(
+                    f"{len(srcs)} sources exceed tenant {tenant!r}'s "
+                    f"in-flight cap of {quota.max_inflight}",
+                    reason="inflight")
         if not srcs:
             return []
         if rec.quarantined:
@@ -354,7 +370,9 @@ class GraphSessionManager:
 
         # verify-mode sampling on the COMPLETED results
         try:
-            self._verify(rec, srcs, outs)
+            if self.verify_fraction > 0.0:
+                with tracing.span("repro.query.verify"):
+                    self._verify(rec, srcs, outs)
         except KernelFaultError as e:
             self._quarantine(rec, str(e))
             return self._serve_reference(rec, srcs)
@@ -490,7 +508,7 @@ class GraphSessionManager:
     # observability
     # ------------------------------------------------------------------
     def stats(self) -> dict[str, Any]:
-        kinds = [e["kind"] for e in self.events]
+        kinds = self._event_counts
         per_tenant: dict[str, dict[str, int]] = {}
         for r in self._sessions.values():
             t = per_tenant.setdefault(
@@ -502,11 +520,11 @@ class GraphSessionManager:
             "sessions": len(self._sessions),
             "bytes_used": self.bytes_used(),
             "byte_budget": self.byte_budget,
-            "evictions": kinds.count("evict"),
-            "timeouts": kinds.count("timeout"),
-            "quarantines": kinds.count("quarantine"),
-            "rejections": kinds.count("admission-reject"),
-            "degraded_serves": kinds.count("degraded-serve"),
-            "verified": kinds.count("verify-pass"),
+            "evictions": kinds["evict"],
+            "timeouts": kinds["timeout"],
+            "quarantines": kinds["quarantine"],
+            "rejections": kinds["admission-reject"],
+            "degraded_serves": kinds["degraded-serve"],
+            "verified": kinds["verify-pass"],
             "tenants": per_tenant,
         }

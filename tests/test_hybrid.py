@@ -9,8 +9,9 @@ import sys
 import numpy as np
 import pytest
 
+from repro import tracing
 from repro.core import reference_bfs
-from repro.core.autotune import TileConfig, clear_cache, stats, tune
+from repro.core.autotune import TileConfig, clear_cache, tune
 from repro.core.bfs import (DEFAULT_PUSH_CAP, BlestProblem, _round_width,
                             make_engine, queue_widths, selected_width)
 from repro.core.bvss import build_bvss
@@ -254,8 +255,7 @@ print("ok")
 @pytest.fixture
 def fresh_tuner():
     clear_cache()
-    before = dict(stats)
-    yield before
+    yield
     clear_cache()
 
 
@@ -266,10 +266,10 @@ def test_autotune_prepare_caches_winning_config(fresh_tuner):
     pb1 = prepare(g1, engine="blest", use_kernels=False, autotune=True)
     assert isinstance(pb1.tile_config, TileConfig)
     assert pb1.tile_config.source == "tuned"
-    runs_after_first = stats["tune_runs"]
+    runs_after_first = tracing.counter("autotune.tune_runs")
     pb2 = prepare(g2, engine="blest", use_kernels=False, autotune=True)
     assert pb2.tile_config.source == "cached"
-    assert stats["tune_runs"] == runs_after_first, "re-tuned a cached class"
+    assert tracing.counter("autotune.tune_runs") == runs_after_first, "re-tuned a cached class"
     assert pb2.tile_config.pull_widths == pb1.tile_config.pull_widths
     assert pb2.tile_config.push_cap == pb1.tile_config.push_cap
     # the tuned engine still answers correctly
@@ -279,11 +279,11 @@ def test_autotune_prepare_caches_winning_config(fresh_tuner):
 
 def test_autotune_env_escape_hatch(fresh_tuner, monkeypatch):
     monkeypatch.setenv("BLEST_AUTOTUNE", "0")
-    runs0 = stats["tune_runs"]
+    runs0 = tracing.counter("autotune.tune_runs")
     p = BlestProblem.build(build_bvss(gen.grid2d(16, 16)))
     cfg = tune(p, use_kernels=False)
     assert cfg.source == "disabled"
-    assert stats["tune_runs"] == runs0, "BLEST_AUTOTUNE=0 still measured"
+    assert tracing.counter("autotune.tune_runs") == runs0, "BLEST_AUTOTUNE=0 still measured"
     assert cfg.pull_widths == tuple(queue_widths(p.num_vss, 2))
     assert cfg.push_cap == DEFAULT_PUSH_CAP
 

@@ -111,3 +111,29 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("lazy", [False, True], ids=["eager", "lazy"])
+def test_engine_stages_compile_for_v5e(one_chip, monkeypatch, lazy):
+    """The counted single-source engine compiles for the chip with its
+    Mosaic kernels, and every fusion and kernel of its level loop maps to
+    one of the three named stages."""
+    from repro import tracing
+    from repro.core.bfs import BlestProblem, make_blest_bfs
+    from repro.core.bvss import build_bvss
+    from repro.graphs import generators as gen
+
+    monkeypatch.setenv("BLEST_INTERPRET", "0")
+    p = BlestProblem.build(build_bvss(gen.rmat(10, 16, seed=1)))
+    fn = make_blest_bfs(p, lazy=lazy, counters=True)
+    text = fn.lower(jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+                    ).compile().as_text()
+    stages = tracing.stage_map(text)
+    comps, entry = tracing.parse_hlo(text)
+    loop = [n for i in comps[entry] if i.opcode == "while"
+            for c in i.control for n in tracing.scheduled(comps, c)]
+    ops = [i for n in loop for i in comps[n]
+           if i.opcode in ("fusion", "custom-call")]
+    kernels = [i for i in ops if i.opcode == "custom-call"]
+    assert kernels and "tpu_custom_call" in text
+    assert all(stages.get(i.name) in tracing.STAGES for i in ops)
