@@ -21,14 +21,13 @@ cumsum stream-compaction; `atomicOr`/`REDG` becomes scatter-max of byte
 marks; the Alg. 3 stage-2 word sweep is a dense vectorised pass, which is
 exactly what the VPU wants.
 
-The ``blest``/``blest_lazy`` level step is FUSED (DESIGN.md §2.3): one
-batched BVSS pull over the compacted queue (Pallas ``bvss_pull`` by
-default), one scatter, and one fused finalise/pack/set-flag sweep
-(``finalize_pack_sweep``).  The queue is processed at one of two static
-widths chosen on-device from the live VSS count ("bucketing") — the
-XLA-compatible stand-in for dynamically-sized kernel launches, so
-small-frontier levels of high-diameter graphs don't pay the full-queue
-cost.  The seed's sequential per-block ``while_loop`` is gone.
+The ``blest``/``blest_lazy`` level step is FUSED (DESIGN.md §2.3): a
+BVSS pull over the compacted queue (Pallas ``bvss_pull`` by default), its
+scatter, and one fused finalise/pack/set-flag sweep
+(``finalize_pack_sweep``).  The pull runs in fixed chunks of
+``PULL_CHUNK`` queue slots, as many as the live VSS count needs — the
+XLA-compatible stand-in for a dynamically-sized kernel launch, so a level
+pays for its live VSSs (plus under one chunk), not for a static width.
 
 The BVSS engines are MESH-NATIVE (DESIGN.md §2.4): a
 :class:`BlestProblem` built from a row-sharded BVSS
@@ -63,6 +62,9 @@ from repro.kernels.ref import bvss_push_ref, finalize_pack_ref
 INF = np.int32(np.iinfo(np.int32).max)
 
 PULL_TILE = 128  # queue widths are padded to this (bvss_pull tile size)
+#: queue slots one chunk of the single-source pull gathers, pulls and
+#: scatters; a pull level runs ceil(live VSSs / PULL_CHUNK) chunks
+PULL_CHUNK = 4 * PULL_TILE
 
 
 # ---------------------------------------------------------------------------
@@ -238,7 +240,7 @@ class _BlestState(NamedTuple):
     F: jnp.ndarray       # (n_fwords,) uint32 packed frontier (global; under
                          #   shard_map each shard carries the gathered copy)
     Q: jnp.ndarray       # (qcap,) int32 compacted VSS queue, dummy-padded
-    count: jnp.ndarray   # int32 live VSS count (LOCAL: bucket choice)
+    count: jnp.ndarray   # int32 live VSS count (LOCAL: chunks to pull)
     marks: jnp.ndarray   # (n + 1,) uint8 lazy scratch ((1,) dummy when eager)
     unvisited: jnp.ndarray  # int32 GLOBAL unvisited-vertex count (the
                             #   Beamer-style saturation guard of the
@@ -248,32 +250,38 @@ class _BlestState(NamedTuple):
                                       #   (LEVEL_COUNTERS); None = uncounted
 
 
-#: the counted engine's device counters, in the order it returns them,
-#: then ``pull_levels.<i>`` for each ladder rung (narrowest first):
-#: ``levels`` loop iterations, ``push_levels``, ``pull_slots`` the sum of
-#: the pull widths chosen and ``live_vss`` the sum of the live VSS count,
-#: both over pull levels
-LEVEL_COUNTERS = ("levels", "push_levels", "pull_slots", "live_vss")
+#: the counted engine's device counters, in the order it returns them:
+#: ``levels`` loop iterations, ``push_levels``, and over pull levels
+#: ``pull_slots`` the queue slots pulled, ``live_vss`` the sum of the
+#: live VSS count and ``pull_chunks`` the chunks run
+LEVEL_COUNTERS = ("levels", "push_levels", "pull_slots", "live_vss",
+                  "pull_chunks")
 
 
 def level_counter_names(n: int) -> tuple[str, ...]:
     """Names of a counted engine's ``n`` counters."""
-    rungs = n - len(LEVEL_COUNTERS)
-    return LEVEL_COUNTERS + tuple(f"pull_levels.{i}" for i in range(rungs))
+    if n != len(LEVEL_COUNTERS):
+        raise ValueError(f"a counted engine returns {len(LEVEL_COUNTERS)} "
+                         f"counters, got {n}")
+    return LEVEL_COUNTERS
 
 
-def _tally(tally: jnp.ndarray | None, use_push, widths: list[int], count
+def chunk_ceil(x):
+    """``x`` rounded up to whole ``PULL_CHUNK``s: the queue slots a pull
+    level over ``x`` live VSSs processes (a Python or a traced int)."""
+    return (x + PULL_CHUNK - 1) // PULL_CHUNK * PULL_CHUNK
+
+
+def _tally(tally: jnp.ndarray | None, use_push, count
            ) -> jnp.ndarray | None:
     """Add one level to the counters after ``levels`` (the loop counts its
-    own iterations): push or pull, and a pull level's width and rung."""
+    own iterations): push or pull, and a pull level's slots and chunks."""
     if tally is None:
         return None
     pull = 1 - jnp.asarray(use_push, dtype=jnp.int32)
-    rung = sum((count > w).astype(jnp.int32) for w in widths[:-1])
-    return tally + jnp.concatenate([
-        jnp.stack([1 - pull, pull * selected_width(widths, count),
-                   pull * count]),
-        pull * jax.nn.one_hot(rung, len(widths), dtype=jnp.int32)])
+    chunks = pull * (chunk_ceil(count) // PULL_CHUNK)
+    return tally + jnp.stack([1 - pull, chunks * PULL_CHUNK, pull * count,
+                              chunks])
 
 
 def _round_width(x: int) -> int:
@@ -282,7 +290,9 @@ def _round_width(x: int) -> int:
 
 def queue_widths(num_vss: int, buckets: int) -> list[int]:
     """Static queue widths, smallest first; the on-device live VSS count
-    picks one via a cond chain (DESIGN §2.3/§2.8).
+    picks one via a cond chain (DESIGN §2.3/§2.8).  The multi-source and
+    2-D engines run this ladder; the single-source engines pull in
+    ``PULL_CHUNK``s and take only its last width, as the queue capacity.
 
     The ladder is geometric with ratio 8: ``buckets`` graduations
     ``num_vss / 8^(buckets-1), ..., num_vss / 8, num_vss`` rounded up to
@@ -413,15 +423,20 @@ def make_queue_history(qcap: int, max_levels: int, dummy_vss: int
 
 
 def _make_hybrid_step(dev, pull: PullFn, push: PushFn | None, sigma: int,
-                      n_rows: int, widths: list[int], *, lazy: bool,
+                      n_rows: int, qcap: int, *, lazy: bool,
                       direction: str, num_vss: int, n_fwords: int,
                       dummy_vertex: int, R: int, push_cap: int,
                       alpha: float) -> Callable:
     """The direction-optimizing level step (DESIGN §2.3/§2.8) — the ONE
     step body both the single-device and the shard_map'd engines run.
 
-    Pull side: the bucketed gather → pull → update over the compacted VSS
-    queue, width chosen from the graduated ladder by the live count.
+    Pull side: a loop over the compacted VSS queue in chunks of
+    ``PULL_CHUNK`` slots, ``ceil(count / PULL_CHUNK)`` of them: each
+    gathers its VSSs' masks and frontier bytes, pulls, and scatters.  The
+    pull reads only the frontier, which no chunk changes, and the scatter
+    (min of the level, max of the marks) is idempotent, so the chunks
+    give the one-shot answer exactly.  ``qcap`` (the queue length) must
+    be a multiple of ``PULL_CHUNK``, so no chunk's start is clamped.
     Push side: compact the frontier BITS into a vertex queue, expand each
     vertex into the ≤ R VSSs of its own slice set, and resolve each
     (vertex, VSS) pair with the one-hot push kernel — processing width
@@ -431,55 +446,67 @@ def _make_hybrid_step(dev, pull: PullFn, push: PushFn | None, sigma: int,
     vertex queue to the full rounded vertex count so nothing is dropped);
     "auto" picks per level on device: push iff the frontier fits the cap,
     the frontier is small against the unvisited remainder (the Beamer-α
-    guard), and push's static cost undercuts the pull width the ladder
-    would select.  When push's static cost cannot beat even the full pull
-    width, auto compiles to pure pull (no dead branch).
+    guard), and push's static cost undercuts the slots the chunked pull
+    would process.  When push's static cost cannot beat even the full
+    queue, auto compiles to pure pull (no dead branch).
 
     ``n_rows`` is the scatter extent: the global ``n`` single-device, the
-    shard's ``rows_per_shard`` under a mesh (row ids are local there —
-    while the frontier words, and hence the push vertex queue, are GLOBAL
-    replicas, so every shard expands the same vertices into its own local
-    VSS ids and both cond branches stay collective-free)."""
+    shard's ``rows_per_shard`` under a mesh (row ids are local there, and
+    each shard loops over its own live count — while the frontier words,
+    and hence the push vertex queue, are GLOBAL replicas, so every shard
+    expands the same vertices into its own local VSS ids and both cond
+    branches stay collective-free)."""
     if direction not in DIRECTIONS:
         raise ConfigError(
             f"direction must be one of {DIRECTIONS}, got {direction!r}")
 
+    def start(state: _BlestState) -> jnp.ndarray:
+        """What a level scatters into: the levels (eager), or the lazy
+        marks, zeroed once per level."""
+        if not lazy:
+            return state.levels
+        with jax.named_scope("bfs.update"):
+            return jnp.zeros((n_rows + 1,), dtype=jnp.uint8)
+
     @jax.named_scope("bfs.update")
-    def scatter(state: _BlestState, rows, h, lvl) -> _BlestState:
+    def scatter(out: jnp.ndarray, rows, h, lvl) -> jnp.ndarray:
         if lazy:
             # Alg. 3 stage 1: fire-and-forget mark (REDG analogue)
-            marks = jnp.zeros((n_rows + 1,), dtype=jnp.uint8)
-            marks = marks.at[rows].max(h.astype(jnp.uint8))
-            return state._replace(marks=marks)
+            return out.at[rows].max(h.astype(jnp.uint8))
         # Alg. 2: eager visited-check-and-set (ATOMG analogue):
         # scatter-min leaves already-visited levels untouched
         upd = jnp.where(h, lvl, INF).astype(jnp.int32)
-        return state._replace(levels=state.levels.at[rows].min(upd))
+        return out.at[rows].min(upd)
 
-    def pull_update(state: _BlestState, lvl, width: int) -> _BlestState:
-        """gather → pull → update over the first ``width`` queue slots
-        (all live entries: the queue is compacted and count <= width)."""
-        ids = jax.lax.slice_in_dim(state.Q, 0, width)
-        fbytes = _frontier_bytes(state.F, dev.virtual_to_real[ids], sigma)
-        hits = pull(dev.masks[ids], fbytes, sigma)       # (width, spw, 32)
-        return scatter(state, dev.row_ids[ids].reshape(-1),
-                       hits.reshape(-1), lvl)
+    def finish(state: _BlestState, out: jnp.ndarray, use_push: bool
+               ) -> _BlestState:
+        state = state._replace(**{"marks" if lazy else "levels": out})
+        return state._replace(tally=_tally(state.tally, use_push,
+                                           state.count))
 
     def pull_step(state: _BlestState, lvl) -> _BlestState:
+        def chunk(i, out):
+            ids = jax.lax.dynamic_slice_in_dim(state.Q, i * PULL_CHUNK,
+                                               PULL_CHUNK)
+            fbytes = _frontier_bytes(state.F, dev.virtual_to_real[ids],
+                                     sigma)
+            hits = pull(dev.masks[ids], fbytes, sigma)  # (C, spw, 32)
+            return scatter(out, dev.row_ids[ids].reshape(-1),
+                           hits.reshape(-1), lvl)
+
         with jax.named_scope("bfs.expand"):
-            out = select_width(widths, state.count,
-                               lambda w: pull_update(state, lvl, w))
-        return out._replace(
-            tally=_tally(state.tally, False, widths, state.count))
+            out = jax.lax.fori_loop(0, chunk_ceil(state.count) // PULL_CHUNK,
+                                    chunk, start(state))
+        return finish(state, out, False)
 
     if direction == "pull":
         return pull_step
 
     pqcap = _round_width(push_cap)
     push_cost = pqcap * R
-    if direction == "auto" and push_cost >= widths[-1]:
-        # push can never undercut even the full pull width (e.g. a hub
-        # set blew up max_vss_per_set): compile the pure pull step
+    if direction == "auto" and push_cost >= qcap:
+        # push can never undercut even the full queue (e.g. a hub set
+        # blew up max_vss_per_set): compile the pure pull step
         return pull_step
     compact_vertices = make_vertex_compactor(n_fwords, dummy_vertex, pqcap)
 
@@ -490,10 +517,9 @@ def _make_hybrid_step(dev, pull: PullFn, push: PushFn | None, sigma: int,
             bits = jnp.broadcast_to((VQ % sigma).astype(jnp.int32)[:, None],
                                     (pqcap, R)).reshape(-1)
             hits = push(dev.masks[ids], bits, sigma)     # (pqcap*R, spw, 32)
-            out = scatter(state, dev.row_ids[ids].reshape(-1),
+            out = scatter(start(state), dev.row_ids[ids].reshape(-1),
                           hits.reshape(-1), lvl)
-        return out._replace(
-            tally=_tally(state.tally, True, widths, state.count))
+        return finish(state, out, True)
 
     if direction == "push":
         return push_step
@@ -503,8 +529,7 @@ def _make_hybrid_step(dev, pull: PullFn, push: PushFn | None, sigma: int,
             fcount = jnp.sum(jax.lax.population_count(state.F)
                              ).astype(jnp.int32)
             use_push = ((fcount <= push_cap)
-                        & (jnp.int32(push_cost)
-                           < selected_width(widths, state.count))
+                        & (jnp.int32(push_cost) < chunk_ceil(state.count))
                         & (fcount * jnp.float32(alpha)
                            <= state.unvisited.astype(jnp.float32)))
         return jax.lax.cond(use_push, push_step, pull_step, state, lvl)
@@ -531,15 +556,16 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
                    counters: bool = False) -> Callable:
     """Build the jitted fused BLEST BFS (Alg. 2 eager / Alg. 3 lazy).
 
-    The level step is direction-optimizing (DESIGN §2.8): the pull side is
-    one batched pull over the compacted queue at a ladder-selected static
-    width; the push side compacts the frontier bits into a vertex queue
-    and expands each vertex's own slice-set VSSs through the one-hot push
-    kernel.  Either way one scatter (min for eager levels, max for lazy
-    marks) and one fused finalise + frontier-pack + set-flag sweep feed
-    cumsum compaction.  A mesh-sharded ``problem`` runs the same pipeline
-    under ``shard_map`` (local pull/push/scatter/finalise, frontier
-    all-gather, psum convergence).
+    The level step is direction-optimizing (DESIGN §2.8): the pull side
+    loops over the compacted queue in chunks of ``PULL_CHUNK`` slots, as
+    many as the live VSS count needs; the push side compacts the frontier
+    bits into a vertex queue and expands each vertex's own slice-set VSSs
+    through the one-hot push kernel.  Either way the scatter (min for
+    eager levels, max for lazy marks) and one fused finalise +
+    frontier-pack + set-flag sweep feed cumsum compaction.  A
+    mesh-sharded ``problem`` runs the same pipeline under ``shard_map``
+    (local pull/push/scatter/finalise, frontier all-gather, psum
+    convergence).
 
     pull_impl:   custom pull (masks, fbytes, sigma) -> hits; overrides the
                  kernel/jnp switch.
@@ -551,7 +577,10 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
     buckets:     graduations of the pull-width ladder (see
                  :func:`queue_widths`); >= 1, ConfigError otherwise.
     widths:      explicit pull-width ladder (ascending; overrides
-                 ``buckets`` — the autotuner's injection point).
+                 ``buckets`` — the autotuner's injection point).  Only
+                 the 2-D engine runs the ladder; the single-device and
+                 1-D engines pull in chunks and take ``widths[-1]``,
+                 rounded up to whole chunks, as the queue capacity.
     direction:   "auto" (per-level on-device switch), "pull", "push".
     push_cap:    auto-mode frontier cap (None = DEFAULT_PUSH_CAP; forced
                  push always uses the full vertex count).
@@ -574,7 +603,7 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
             f"direction must be one of {DIRECTIONS}, got {direction!r}")
     if widths is None:
         widths = queue_widths(p.num_vss, buckets)
-    qcap = widths[-1]
+    qcap = chunk_ceil(widths[-1])
     max_lv = max_levels if max_levels is not None else p.n + 1
     cap = resolve_push_cap(direction, push_cap, p.n)
 
@@ -598,11 +627,11 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
                               "single-device engine")
         if p.is_2d:
             return _make_blest_bfs_sharded_2d(p, pull=pull, widths=widths,
-                                              qcap=qcap, max_lv=max_lv,
+                                              max_lv=max_lv,
                                               direction=direction)
         return _make_blest_bfs_sharded(p, lazy=lazy, pull=pull, push=push,
-                                       fin_impl=fin_impl, widths=widths,
-                                       qcap=qcap, max_lv=max_lv,
+                                       fin_impl=fin_impl, qcap=qcap,
+                                       max_lv=max_lv,
                                        direction=direction, push_cap=cap,
                                        alpha=alpha)
 
@@ -610,7 +639,7 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
     fin = functools.partial(fin_impl, sigma=sigma, n_fwords=p.n_fwords,
                             n_sets=p.n_sets)
     compact = make_compactor(dev, p.num_vss, qcap)
-    step = _make_hybrid_step(dev, pull, push, sigma, p.n, widths, lazy=lazy,
+    step = _make_hybrid_step(dev, pull, push, sigma, p.n, qcap, lazy=lazy,
                              direction=direction, num_vss=p.num_vss,
                              n_fwords=p.n_fwords, dummy_vertex=p.n,
                              R=p.max_vss_per_set, push_cap=cap, alpha=alpha)
@@ -643,7 +672,7 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
         set0 = jnp.zeros((p.n_sets,), dtype=bool).at[src // sigma].set(True)
         Q, count = compact(set0)
         marks0 = jnp.zeros((p.n + 1 if lazy else 1,), dtype=jnp.uint8)
-        tally = jnp.zeros((len(LEVEL_COUNTERS) - 1 + len(widths),),
+        tally = jnp.zeros((len(LEVEL_COUNTERS) - 1,),
                           dtype=jnp.int32) if counters else None
         state = _BlestState(levels, F, Q, count, marks0,
                             jnp.int32(p.n - 1), count > 0, tally)
@@ -660,8 +689,8 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
 
 
 def _make_blest_bfs_sharded(p: BlestProblem, *, lazy: bool, pull: PullFn,
-                            push: PushFn, fin_impl, widths: list[int],
-                            qcap: int, max_lv: int, direction: str,
+                            push: PushFn, fin_impl, qcap: int,
+                            max_lv: int, direction: str,
                             push_cap: int, alpha: float) -> Callable:
     """The mesh-native BLEST engine (DESIGN §2.4): the whole level loop is
     ONE ``shard_map``'d ``while_loop`` over the row partition.  Per level,
@@ -694,7 +723,7 @@ def _make_blest_bfs_sharded(p: BlestProblem, *, lazy: bool, pull: PullFn,
         dev = ShardedBVSSDevice(masks[0], row_ids[0], v2r[0], vstart[0],
                                 vend[0])
         compact = make_compactor(dev, p.num_vss, qcap)
-        step = _make_hybrid_step(dev, pull, push, sigma, rps, widths,
+        step = _make_hybrid_step(dev, pull, push, sigma, rps, qcap,
                                  lazy=lazy, direction=direction,
                                  num_vss=p.num_vss, n_fwords=p.n_fwords,
                                  dummy_vertex=p.n, R=p.max_vss_per_set,
@@ -755,7 +784,7 @@ def _make_blest_bfs_sharded(p: BlestProblem, *, lazy: bool, pull: PullFn,
 
 
 def _make_blest_bfs_sharded_2d(p: BlestProblem, *, pull: PullFn,
-                               widths: list[int], qcap: int, max_lv: int,
+                               widths: list[int], max_lv: int,
                                direction: str) -> Callable:
     """The 2-D (row × column) mesh-native BLEST engine (DESIGN §2.4).
 
@@ -791,6 +820,7 @@ def _make_blest_bfs_sharded_2d(p: BlestProblem, *, pull: PullFn,
             "1-D partition holds — use a 1-D mesh or direction='pull'")
     mesh, rax, cax = p.mesh, p.axis, p.col_axis
     sigma = p.sigma
+    qcap = widths[-1]
     rps = p.rows_per_shard
     lwords = rps // 32
     cpb = p.cols_per_block

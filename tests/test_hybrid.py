@@ -13,7 +13,7 @@ from repro import tracing
 from repro.core import reference_bfs
 from repro.core.autotune import TileConfig, clear_cache, tune
 from repro.core.bfs import (DEFAULT_PUSH_CAP, BlestProblem, _round_width,
-                            make_engine, queue_widths, selected_width)
+                            chunk_ceil, make_engine, queue_widths)
 from repro.core.bvss import build_bvss
 from repro.core.multi_source import make_multi_source_bfs
 from repro.core.policy import parents_from_levels, prepare
@@ -134,10 +134,10 @@ def test_auto_mode_genuinely_flips():
     g = FLIP_GRAPH
     b = build_bvss(g)
     p = BlestProblem.build(b)
-    widths = queue_widths(p.num_vss, 2)
+    qcap = chunk_ceil(queue_widths(p.num_vss, 2)[-1])
     pqcap = _round_width(DEFAULT_PUSH_CAP)
     push_cost = pqcap * p.max_vss_per_set
-    assert push_cost < widths[-1], "static bail: graph cannot flip"
+    assert push_cost < qcap, "static bail: graph cannot flip"
     vstart = np.asarray(p.dev.vss_of_vertex_start)
     vend = np.asarray(p.dev.vss_of_vertex_end)
     lv = reference_bfs(g, 0)
@@ -147,7 +147,7 @@ def test_auto_mode_genuinely_flips():
         rep = np.minimum(np.unique(fverts // b.sigma) * b.sigma, g.n - 1)
         count = int((vend[rep] - vstart[rep]).sum())
         use_push = (len(fverts) <= DEFAULT_PUSH_CAP
-                    and push_cost < int(selected_width(widths, count))
+                    and push_cost < chunk_ceil(count)
                     and len(fverts) * 4.0 <= int(np.sum(lv > L)))
         n_push += use_push
         n_pull += not use_push

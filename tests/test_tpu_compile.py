@@ -3,7 +3,8 @@
 Interpret mode (the rest of the suite) never checks Mosaic's tiling rules
 or VMEM limits, so each entry point is compiled here, ahead of time, for
 one chip of a described ``v5e:2x2`` topology at deployment widths: 4096
-queued VSSs, 64 stacked sources, σ = 8, 2^20 vertices.  Nothing runs;
+queued VSSs (and the single-source pull's chunk), 64 stacked sources,
+σ = 8, 2^20 vertices.  Nothing runs;
 each test asserts the compiled program holds the Mosaic kernel
 (``tpu_custom_call``).
 
@@ -18,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import kernels as K
+from repro.core.bfs import PULL_CHUNK
 
 B, S, SIGMA, N = 4096, 64, 8, 1 << 20
 SPW = 32 // SIGMA
@@ -29,6 +31,10 @@ CASES = {
         lambda m, f: K.bvss_pull(m, f, sigma=SIGMA, layout="lanes",
                                  interpret=False),
         [((B, 32), U32), ((B,), U32)]),
+    "bvss_pull[lanes,chunk]": (
+        lambda m, f: K.bvss_pull(m, f, sigma=SIGMA, layout="lanes",
+                                 interpret=False),
+        [((PULL_CHUNK, 32), U32), ((PULL_CHUNK,), U32)]),
     "bvss_pull[rows]": (
         lambda m, f: K.bvss_pull(m, f, sigma=SIGMA, layout="rows",
                                  interpret=False),
@@ -102,6 +108,10 @@ def one_chip():
     finally:
         jax.config.update("jax_enable_compilation_cache", enabled)
         compilation_cache.reset_cache()
+        # the engine test traces the kernels with BLEST_INTERPRET=0; the
+        # kernels' jit caches key on shapes alone, so those compiled-mode
+        # traces would serve later CPU tests of the same process
+        jax.clear_caches()
 
 
 @pytest.mark.parametrize("name", sorted(CASES))

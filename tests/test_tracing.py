@@ -10,8 +10,8 @@ import pytest
 
 from repro import tracing
 from repro.core import reference_bfs
-from repro.core.bfs import (INF, BlestProblem, level_counter_names,
-                            make_blest_bfs, make_engine)
+from repro.core.bfs import (INF, PULL_CHUNK, BlestProblem,
+                            level_counter_names, make_blest_bfs, make_engine)
 from repro.core.bvss import build_bvss
 from repro.core.policy import PrepareOptions, prepare
 from repro.errors import ConfigError
@@ -57,10 +57,11 @@ def test_level_counters_match_the_reference(graph, engine, direction):
         np.testing.assert_array_equal(lv, want)
         c = tracing.last("repro.query").counts
         assert c["bfs.levels"] == want[want != INF].max() + 1
-        pulls = sum(v for k, v in c.items()
-                    if k.startswith("bfs.pull_levels."))
-        assert c["bfs.push_levels"] + pulls == c["bfs.levels"]
-        assert c["bfs.pull_slots"] >= c["bfs.live_vss"] >= 0
+        pulls = c["bfs.levels"] - c["bfs.push_levels"]
+        assert 0 <= pulls <= c["bfs.pull_chunks"]
+        assert c["bfs.pull_slots"] == PULL_CHUNK * c["bfs.pull_chunks"]
+        assert (0 <= c["bfs.pull_slots"] - c["bfs.live_vss"]
+                < PULL_CHUNK * max(pulls, 1))
         if direction == "push":
             assert c["bfs.push_levels"] == c["bfs.levels"]
 
@@ -73,7 +74,8 @@ def test_counted_engine_keeps_the_public_signature():
     lv2, counts = make_blest_bfs(p, lazy=False, counters=True)(0)
     np.testing.assert_array_equal(np.asarray(lv2), np.asarray(lv))
     names = level_counter_names(len(counts))
-    assert names[:4] == ("levels", "push_levels", "pull_slots", "live_vss")
+    assert names == ("levels", "push_levels", "pull_slots", "live_vss",
+                     "pull_chunks")
     with pytest.raises(ConfigError):
         make_engine(g, "csr_push", counters=True)
 
