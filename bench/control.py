@@ -4,11 +4,13 @@
     python bench/control.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Does everything ``bench/run.py`` does and prints its result line for the
-program.  Then, once the window has closed, it puts the control's levels
-(the plain reference stopped one level short, see ``bench/check.py``)
+program.  Then, once the window has closed, it puts the control's answers
 into the recorded answers in place of the program's, runs the same check
 on them and prints that result line last: its ``correct`` has to read
-false.  The benchmark's own runs do not run it.
+false.  The control belongs to the mix's answer check, which says what
+it is in its ``CONTROL`` (``bench/check.py``; for BFS levels, the plain
+reference stopped one level short).  The benchmark's own runs do not
+run it.
 """
 import time
 
@@ -28,8 +30,7 @@ def main(argv=None) -> int:
     run = run_cell(bench_run.ROOT, args.workload, args.seed, args.seconds,
                    bool(args.trace), t_start=T_START, control=True)
     print(json.dumps(bench_run.report(run)), flush=True)
-    bench_run.log("control: the reference stopped one level short, in the "
-                  "program's place")
+    bench_run.log(f"control: {run.answer_check.CONTROL}")
     print(json.dumps(bench_run.report(run, run.control)), flush=True)
     return 0
 

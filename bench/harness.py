@@ -2,15 +2,18 @@
 
 Order of a run (one process, one chip):
 
-1. generate the graph from the seed (the configuration's generator);
+1. generate the graph, and its edge weights where the configuration's
+   generator draws them, from the seed;
 2. ``GraphSessionManager.open_session`` -> ``repro.prepare`` (ordering,
-   BVSS build, engine), timed as ``prepare_s``;
+   BVSS build, the weight plane where there are weights, engine), timed
+   as ``prepare_s``;
 3. the traffic driver warms up the cell's own engine (``compile_s``);
 4. the window: the driver offers its traffic for ``seconds``; with
    ``trace`` the profiler records it, with the benchmark's host spans;
 5. peak device memory is read, the program's state is freed;
-6. every answer is compared with the plain reference (with ``control``,
-   the control's answers are then checked in their place, apart);
+6. every answer is compared with the plain reference by the mix's answer
+   check (``bench/check.py``; with ``control``, the control's answers are
+   then checked in their place, apart);
 7. each metric's reader turns what was recorded into its number.
 """
 from __future__ import annotations
@@ -23,7 +26,7 @@ import time
 
 import numpy as np
 
-from bench.check import Verdict, check, control_answers
+from bench.check import CsrGraph, Verdict
 from bench.spans import Spans
 from bench.spec import Cell, load_cell
 
@@ -42,7 +45,7 @@ CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
 @dataclasses.dataclass
 class Answer:
     src: int
-    levels: np.ndarray | None
+    value: np.ndarray | None    # levels, distances: what the verb returns
     due: float                  # host clock, seconds
     done: float | None          # when the client had the result
 
@@ -87,11 +90,12 @@ class Run:
         self.trace = None
         self.memory_peak_bytes = 0
         # filled as the run goes: devices, graph (n, indptr, indices),
-        # degrees, mgr and name of the session, plan, ordering, engine
+        # weights (per arc, or None), degrees, answer_check, mgr and name
+        # of the session, plan, ordering, engine
 
-    def answer(self, src: int, levels, due: float, done: float | None
+    def answer(self, src: int, value, due: float, done: float | None
                ) -> None:
-        self.answers.append(Answer(src, levels, due, done))
+        self.answers.append(Answer(src, value, due, done))
 
 
 def _peak_bytes(devices: list) -> int:
@@ -120,10 +124,12 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     compiles = _Compiles()
     driver = cell.plugin("drivers", cell.traffic["kind"])
     gen = cell.plugin("graphs", cell.config["generator"])
+    run.answer_check = cell.plugin("answers", cell.answers)
 
     t = time.perf_counter()
-    n, indptr, indices = gen.generate(cell.config,
-                                      np.random.default_rng([seed, 1]))
+    n, indptr, indices, *weights = gen.generate(
+        cell.config, np.random.default_rng([seed, 1]))
+    run.weights = weights[0] if weights else None
     run.graph = (n, indptr, indices)
     run.degrees = np.diff(indptr)
     run.setup["generate_s"] = time.perf_counter() - t
@@ -131,7 +137,9 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     t = time.perf_counter()
     run.mgr = GraphSessionManager()
     run.name = cell.name
-    sess = run.mgr.open_session(run.name, Graph(n, indptr, indices))
+    weighted = {} if run.weights is None else {"weights": run.weights}
+    sess = run.mgr.open_session(run.name, Graph(n, indptr, indices),
+                                **weighted)
     run.setup["prepare_s"] = time.perf_counter() - t
     run.ordering, run.engine = sess.ordering, sess.engine_name
     run.num_vss = int(sess.bvss.num_vss)
@@ -164,11 +172,12 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     run.mgr = None
     gc.collect()
 
-    refs: dict = {}
-    run.verdict = check(indptr, indices, run.answers, refs)
+    graph = CsrGraph(n, indptr, indices, run.weights)
+    ac, refs = run.answer_check, {}
+    run.verdict = ac.check(graph, run.answers, refs)
     if control:
-        run.control = check(indptr, indices, control_answers(
-            indptr, indices, run.answers, refs), refs)
+        run.control = ac.check(
+            graph, ac.control_answers(graph, run.answers, refs), refs)
     if trace:
         from bench import xplane
         try:

@@ -1,8 +1,9 @@
-"""The benchmark's plain BFS: level-synchronous, top-down, NumPy over CSR.
+"""The benchmark's plain references: a level-synchronous, top-down BFS in
+NumPy over CSR, and SciPy's float64 Dijkstra for weighted distances.
 
-Independent of the program under test: it reads only the generated CSR
-arrays.  Unreached vertices hold ``INF`` (the int32 maximum), as in the
-program's answers.
+Independent of the program under test: they read only the generated CSR
+arrays and weights.  Unreached vertices hold ``INF`` (the int32 maximum)
+in levels, as in the program's answers, and ``+inf`` in distances.
 """
 from __future__ import annotations
 
@@ -38,3 +39,20 @@ def edges_reached(indptr: np.ndarray, levels: np.ndarray) -> int:
     half the degree sum over reached vertices of a symmetric CSR."""
     deg = np.diff(indptr)
     return int(deg[levels != INF].sum()) // 2
+
+
+def sssp_distances(indptr: np.ndarray, indices: np.ndarray,
+                   weights: np.ndarray | None, src: int, *,
+                   predecessors: bool = False):
+    """Float64 shortest-path length of every vertex from ``src`` over the
+    arcs of the CSR with their ``weights`` (1 each when ``None``),
+    ``+inf`` where unreached.  With ``predecessors``, also each vertex's
+    parent in the shortest-path tree (-9999 at the root and unreached)."""
+    from scipy.sparse import csr_matrix
+    from scipy.sparse.csgraph import dijkstra
+    n = len(indptr) - 1
+    w = (np.ones(len(indices)) if weights is None
+         else np.asarray(weights, dtype=np.float64))
+    arcs = csr_matrix((w, indices, indptr), shape=(n, n))
+    return dijkstra(arcs, directed=True, indices=int(src),
+                    return_predecessors=predecessors)

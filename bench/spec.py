@@ -8,6 +8,8 @@ name, so that a new cell is added with new files and entries only:
 * traffic mix     ``bench/traffic/<traffic>.json``;
 * generator       ``bench/graphs/<config["generator"]>.py``;
 * traffic driver  ``bench/drivers/<mix["kind"]>.py``;
+* answer check    ``bench/answers/<mix["answers"]>.py``, ``levels`` when
+                  the mix names none (``bench/check.py``);
 * metric reader   ``bench/metrics/<metric name>.py``.
 """
 from __future__ import annotations
@@ -29,6 +31,10 @@ class Cell:
     end_to_end: list[dict]
     per_layer: list[dict]
     bench_dir: str
+
+    @property
+    def answers(self) -> str:
+        return self.traffic.get("answers", "levels")
 
     def plugin(self, kind: str, name: str) -> ModuleType:
         return load_plugin(os.path.join(self.bench_dir, kind, name + ".py"))

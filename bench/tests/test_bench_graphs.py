@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from bench.graphs import kronecker, rgg
+from bench.graphs.csr import edge_weights
 from bench.reference import INF, bfs_levels, edges_reached
 
 KRON = {"scale": 10, "edgefactor": 16, "A": 0.57, "B": 0.19, "C": 0.19}
@@ -104,7 +105,9 @@ def test_reference_depth_cap_and_edge_count():
 def test_reference_imports_nothing_of_the_program():
     bench = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for rel in ("reference.py", "check.py", "loadgen.py", "xplane.py",
-                "graphs/kronecker.py", "graphs/rgg.py", "graphs/csr.py"):
+                "graphs/kronecker.py", "graphs/rgg.py", "graphs/csr.py",
+                "answers/levels.py", "answers/distances.py",
+                "hop_bound.py"):
         with open(os.path.join(bench, rel)) as f:
             tree = ast.parse(f.read())
         for node in ast.walk(tree):
@@ -115,6 +118,40 @@ def test_reference_imports_nothing_of_the_program():
             else:
                 continue
             assert not any(n.split(".")[0] == "repro" for n in names), rel
+
+
+@pytest.mark.parametrize("mod,cfg", [(kronecker, KRON), (rgg, RGG)],
+                         ids=["kronecker", "rgg"])
+def test_edge_weights_are_seeded_and_symmetric(mod, cfg):
+    n, indptr, indices = _gen(mod, cfg, 21)
+    a, b, c = (edge_weights(n, indptr, indices, np.random.default_rng(s))
+               for s in (2**31 + 9, 2**31 + 9, 10))
+    assert a.dtype == np.float32 and a.shape == indices.shape
+    assert np.array_equal(a, b) and not np.array_equal(a, c)
+    assert (a > 0).all() and (a < 1).all()
+    src = np.repeat(np.arange(n), np.diff(indptr))
+    back = np.searchsorted(src * n + indices, indices * n + src)
+    assert np.array_equal(a[back], a)
+    assert len(np.unique(a)) > 0.99 * len(a) / 2
+
+
+class _ZerosFirst:
+    """A generator whose first draw is all zeros."""
+
+    def __init__(self):
+        self.rng, self.calls = np.random.default_rng(0), 0
+
+    def random(self, size, dtype):
+        self.calls += 1
+        if self.calls == 1:
+            return np.zeros(size, dtype)
+        return self.rng.random(size, dtype=dtype)
+
+
+def test_edge_weights_draw_a_zero_again():
+    n, indptr, indices = _gen(kronecker, KRON, 22)
+    w = edge_weights(n, indptr, indices, _ZerosFirst())
+    assert (w > 0).all()
 
 
 def test_roster_is_seeded_and_keeps_to_the_degree_rule():

@@ -14,7 +14,7 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from bench.check import depth_of, stopped_short
+from bench.answers.levels import depth_of, stopped_short
 from bench.chip import check_devices, peaks
 from bench.harness import metrics, run_cell
 from bench.reference import bfs_levels
@@ -87,6 +87,150 @@ def test_new_config_mix_and_metric_need_no_edit(tmp_path):
     assert run.verdict.correct
     assert set(got) == {"answers_per_s", "setup_s"}
     assert got["answers_per_s"]["value"] > 0
+
+
+# A weighted configuration whose mix names the distances check, and a
+# test-only driver that asks the session for ``sssp``: new files only.
+WEIGHTED_GENERATOR = """\
+from bench.graphs import kronecker
+from bench.graphs.csr import edge_weights
+
+
+def generate(cfg, rng):
+    n, indptr, indices = kronecker.generate(cfg, rng)
+    return n, indptr, indices, edge_weights(n, indptr, indices, rng)
+"""
+
+SSSP_DRIVER = """\
+import time
+
+from bench.loadgen import roster
+
+
+def _session(run):
+    return run.mgr._get(run.name, "default").session
+
+
+def setup(run):
+    mix = run.cell.traffic
+    run.plan = roster(run.rng, run.degrees, int(mix["roster"]),
+                      int(mix["root_min_degree"]))
+    _session(run).sssp(int(run.plan[0]))
+
+
+def window(run, seconds):
+    sess, plan = _session(run), run.plan
+    t0 = time.perf_counter()
+    i = 0
+    while time.perf_counter() < t0 + seconds:
+        src = int(plan[i % len(plan)])
+        due = time.perf_counter()
+        run.answer(src, sess.sssp(src), due, time.perf_counter())
+        i += 1
+    run.window = (t0, run.answers[-1].done)
+
+
+def close(run):
+    pass
+"""
+
+SSSP_CELL = "kron_sssp.sssp"
+
+
+@pytest.fixture(scope="module")
+def weighted(tmp_path_factory):
+    """A tiny benchmark with the weighted cell added, and the files that
+    adding it changed."""
+    root = tiny_root(str(tmp_path_factory.mktemp("weighted")))
+    before = _digests(root)
+    bench = os.path.join(root, "bench")
+    _write(os.path.join(bench, "graphs", "kronecker_weighted.py"),
+           WEIGHTED_GENERATOR)
+    _write(os.path.join(bench, "drivers", "sssp_loop.py"), SSSP_DRIVER)
+    _write(os.path.join(bench, "traffic", "sssp.json"), json.dumps(
+        {"kind": "sssp_loop", "answers": "distances", "roster": 8,
+         "root_min_degree": 1}))
+    with open(os.path.join(bench, "configs", "kron_g500.json")) as f:
+        conf = json.load(f)
+    conf.update(name="kron_sssp", generator="kronecker_weighted", scale=7)
+    _write(os.path.join(bench, "configs", "kron_sssp.json"),
+           json.dumps(conf))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    spec["configs"].append({"name": "kron_sssp", "source": "test",
+                            "file": "bench/configs/kron_sssp.json",
+                            "reduced": ["scale"], "why": "test"})
+    spec["workloads"].append({"name": SSSP_CELL, "config": "kron_sssp",
+                              "traffic": "sssp", "chips": 1, "why": "test"})
+    _write(os.path.join(root, "BENCHMARK.json"), json.dumps(spec))
+    after = _digests(root)
+    return root, {p for p in before if after.get(p) != before[p]}
+
+
+def test_weighted_config_and_answer_check_need_no_edit(weighted):
+    root, changed = weighted
+    assert changed == {"BENCHMARK.json"}
+    run = run_cell(root, SSSP_CELL, SEED + 2, 0.3, False, require_tpu=False,
+                   control=True)
+    assert run.weights is not None and len(run.graph) == 3
+    v, c = run.verdict, run.control
+    assert v.correct and v.checked > 0 and v.levels_run == 0
+    assert 0 <= v.extra["max_rel_error"][0] <= v.extra["max_rel_error"][1]
+    assert c.checked == v.checked and not c.correct
+    line = report(run, c)
+    assert line["correct"] is False
+    assert set(line["checks"]) == {"wrong_answers", "missing_answers",
+                                   "max_rel_error"}
+    assert report(run)["correct"] is True
+
+
+def _distances_unchanged(d: np.ndarray) -> np.ndarray:
+    out = np.full_like(d, np.inf)
+    out[d == 0] = 0             # the state the bucket loop started from
+    return out
+
+
+def _distance_altered(d: np.ndarray) -> np.ndarray:
+    out = d.copy()
+    far = np.flatnonzero(np.isfinite(d))
+    out[far[np.argmax(d[far])]] *= 1 + 1e-4
+    return out
+
+
+def _distances_half(d: np.ndarray) -> np.ndarray:
+    out = d.copy()
+    out[: len(d) // 2] = np.inf
+    return out
+
+
+DISTANCE_FAULTS = {"state_unchanged": _distances_unchanged,
+                   "answer_altered": _distance_altered,
+                   "half_left_out": _distances_half}
+
+
+def _plant_weighted(monkeypatch, fault: str) -> None:
+    from repro import GraphSessionManager
+    from repro.serve.graph_session import GraphSession
+    if fault == "weights_left_out":
+        open_session = GraphSessionManager.open_session
+        monkeypatch.setattr(
+            GraphSessionManager, "open_session",
+            lambda self, name, g, weights=None, **kw:
+            open_session(self, name, g, **kw))
+        return
+    sssp = GraphSession.sssp
+    monkeypatch.setattr(GraphSession, "sssp", lambda self, src, **kw:
+                        DISTANCE_FAULTS[fault](sssp(self, src, **kw)))
+
+
+@pytest.mark.parametrize("fault",
+                         sorted(DISTANCE_FAULTS) + ["weights_left_out"])
+def test_weighted_fault_reads_not_correct(weighted, monkeypatch, fault):
+    _plant_weighted(monkeypatch, fault)
+    run = run_cell(weighted[0], SSSP_CELL, SEED + 3, 0.3, False,
+                   require_tpu=False)
+    assert run.verdict.checked > 0
+    assert not run.verdict.correct
 
 
 def _fake(platform: str, kind: str):
@@ -190,6 +334,7 @@ def test_program_passes_and_control_fails(root, cell):
     assert line["correct"] is False
     assert line["checks"]["wrong_answers"] == {"value": c.checked,
                                                "limit": 0}
+    assert set(line["checks"]) == {"wrong_answers", "missing_answers"}
     got = metrics(run)
     want = {m["name"] for m in run.cell.end_to_end}
     assert set(got) == want
