@@ -12,37 +12,58 @@ driver is a bucketed label-correcting loop — batched delta-stepping:
 * the INNER loop relaxes the current bucket to a fixpoint: the frontier
   (vertices whose distance improved and sits below ``btop``) is compacted
   set-wise through the SAME ``make_compactor`` queue the BFS engines use,
-  pulled through the min-plus tiles against the weight plane, and
-  scatter-``min``'d into the distance vector;
+  then pulled in chunks of ``PULL_CHUNK`` queue slots, as many as the
+  live VSS count needs (the single-source BFS pull's loop, DESIGN §2.3):
+  each chunk gathers its masks, weight-plane rows and σ-column distances,
+  runs the min-plus tile product and scatter-``min``s into the distance
+  rows.  The pull reads only the frontier's distances, which no chunk
+  changes, and the scatter-min is idempotent, so the chunks give the
+  one-shot relaxation exactly;
 * the bucket advance jumps ``btop`` to the bucket holding the smallest
   unsettled distance — empty buckets cost nothing, so the classic Δ
   trade-off (bucket width vs relaxation rounds) only shapes performance,
   never correctness.
 
 Both loops fuse into ONE jitted ``while_loop`` nest per cohort of S
-sources (S stacked distance columns through one tile stream).  A
-row-sharded problem runs the identical loop under ``shard_map``: local
-rows scatter locally, the frontier's distance values all-gather per
-relaxation (the float twin of the frontier-word gather, hoisted out of
-the width ``cond``), and continuation / bucket minima reduce with
-``psum`` / ``pmin`` so every shard stays in lock-step.  A 2-D problem is
-a typed :class:`~repro.errors.ConfigError` (the weighted verbs ship 1-D;
+sources (S stacked distance rows, each lane-dense over the vertices,
+through one tile stream).  The engine runs under three named scopes
+(``repro.tracing.SSSP_STAGES``): ``sssp.expand`` (queue chunks, gathers
+and the min-plus kernel), ``sssp.update`` (the scatter-min) and
+``sssp.bucket`` (the compaction, the settle sweep, the bucket advance,
+the next frontier and the loops' own control).  The device arrays it
+reads (the BVSS and the weight plane) are arguments of the compiled
+program, not constants inside it.
+
+A row-sharded problem runs the identical loop under ``shard_map``: local
+rows scatter locally, each shard pulls over its own live count, the
+frontier's distance values all-gather once per relaxation (the float
+twin of the frontier-word gather, outside the chunk loop), and
+continuation / bucket minima reduce with ``psum`` / ``pmin`` so every
+shard stays in lock-step.  A 2-D problem is a typed
+:class:`~repro.errors.ConfigError` (the weighted verbs ship 1-D;
 DESIGN §2.9).
 """
 from __future__ import annotations
 
-from typing import Callable, Sequence
+from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.analytics.common import pad_cohort
-from repro.core.bfs import (BlestProblem, make_compactor, queue_widths,
-                            select_width)
+from repro.core.bfs import (PULL_CHUNK, BlestProblem, chunk_ceil,
+                            make_compactor)
 from repro.errors import ConfigError
 from repro.kernels import bvss_spmm_minplus_local
 from repro.kernels.ref import bvss_spmm_minplus_ref
+
+#: the counted engine's device counters, in the order it returns them:
+#: inner relaxation ``rounds``, outer ``buckets``, and over the rounds
+#: ``pull_slots`` the queue slots pulled (``PULL_CHUNK`` × chunks),
+#: ``live_vss`` the sum of the live VSS count and ``pull_chunks``
+SSSP_COUNTERS = ("rounds", "buckets", "pull_slots", "live_vss",
+                 "pull_chunks")
 
 
 def default_delta(weights: np.ndarray) -> float:
@@ -66,212 +87,234 @@ def _next_btop(rem: jnp.ndarray, btop: jnp.ndarray,
     return jnp.where(jnp.isfinite(rem), nbt, btop)
 
 
+class SSSPEngine(NamedTuple):
+    """A built delta-stepping engine: ``fn(arrays, sources, delta)``,
+    jitted (or compiled), and the device arrays it reads — the BVSS and
+    the weight plane, passed as arguments so that no compiled program
+    carries a copy of the graph.  Call it as ``engine(sources (S,) i32,
+    delta () f32)``: the distances ``(S, n)`` f32, with the counters
+    (:data:`SSSP_COUNTERS`) as well when it was built with them."""
+
+    fn: Callable
+    arrays: tuple
+    n_sources: int
+
+    def __call__(self, sources, delta):
+        return self.fn(self.arrays, sources, delta)
+
+    def compile(self) -> "SSSPEngine":
+        """The engine compiled ahead of its first call."""
+        specs = (jax.ShapeDtypeStruct((self.n_sources,), jnp.int32),
+                 jax.ShapeDtypeStruct((), jnp.float32))
+        return self._replace(fn=self.fn.lower(self.arrays, *specs).compile())
+
+
 def make_sssp(problem: BlestProblem, wplane: jnp.ndarray, n_sources: int, *,
-              use_kernel: bool = True, buckets: int = 2,
-              max_rounds: int | None = None) -> Callable:
-    """Build jitted ``f(sources (S,) i32, delta () f32) -> dist (n, S) f32``
-    over ``problem`` (ids are the problem's own).  ``wplane`` is the
-    device weight plane ``prepare(..., weights=...)`` committed
-    (``PreparedBFS.wplane``); its dummy row makes padded queue entries
-    relax nothing.  Single-device and 1-D row-sharded; 2-D raises
-    :class:`~repro.errors.ConfigError`."""
+              use_kernel: bool = True, max_rounds: int | None = None,
+              counters: bool = False) -> SSSPEngine:
+    """Build the delta-stepping engine ``f(sources (S,) i32, delta () f32)
+    -> dist (S, n) f32`` over ``problem`` (ids are the problem's own).
+    ``wplane`` is the device weight plane ``prepare(..., weights=...)``
+    committed (``PreparedBFS.wplane``); its dummy row makes padded queue
+    entries relax nothing.  ``counters``: also return the int32 counters
+    of :data:`SSSP_COUNTERS` (single-device only).  Single-device and 1-D
+    row-sharded; 2-D raises :class:`~repro.errors.ConfigError`."""
     if wplane is None:
         raise ConfigError(
             "sssp needs a weight plane: prepare(..., weights=...) or let "
             "GraphSession default to unit weights")
+    impl = None if use_kernel else bvss_spmm_minplus_ref
+    cap = max_rounds if max_rounds is not None else problem.n + 2
     if problem.mesh is not None:
         if problem.is_2d:
             raise ConfigError(
                 "sssp is not supported on a 2-D (row × column) mesh yet — "
                 "the weighted verbs ship 1-D row-sharded (DESIGN §2.9)")
-        return _make_sssp_sharded(problem, wplane, n_sources,
-                                  use_kernel=use_kernel, buckets=buckets,
-                                  max_rounds=max_rounds)
-    return _make_sssp_single(problem, wplane, n_sources,
-                             use_kernel=use_kernel, buckets=buckets,
-                             max_rounds=max_rounds)
+        if counters:
+            raise ConfigError("sssp counters exist only on the "
+                              "single-device engine")
+        return _make_sssp_sharded(problem, wplane, n_sources, impl=impl,
+                                  cap=cap)
+    return _make_sssp_single(problem, wplane, n_sources, impl=impl, cap=cap,
+                             counters=counters)
+
+
+def _relaxation(dev, wplane: jnp.ndarray, p: BlestProblem, n_sources: int,
+                impl) -> Callable:
+    """``relax(dist, xg) -> (dist, (slots, live, chunks))``: one frontier
+    relaxation, the ONE body the single-device and the sharded engines
+    run.  ``xg`` (S, ≥ n_sets·σ) holds the frontier's distances over the
+    GLOBAL columns, +inf off the frontier; ``dist`` (S, rows) the rows
+    this device owns, scattered through ``row_ids`` (dummy rows past the
+    end are dropped or masked by the caller).  The queue capacity is the
+    VSS count rounded up to whole chunks, padded with the dummy VSS, so
+    no chunk's start is clamped."""
+    sigma, n_sets, S = p.sigma, p.n_sets, n_sources
+    compact = make_compactor(dev, p.num_vss, chunk_ceil(max(p.num_vss, 1)))
+
+    def relax(dist: jnp.ndarray, xg: jnp.ndarray):
+        with jax.named_scope("sssp.bucket"):
+            set_active = (xg[:, :n_sets * sigma].reshape(S, n_sets, sigma)
+                          < jnp.inf).any(axis=(0, 2))
+            Q, count = compact(set_active)
+
+        def chunk(i, d):
+            ids = jax.lax.dynamic_slice_in_dim(Q, i * PULL_CHUNK, PULL_CHUNK)
+            y = bvss_spmm_minplus_local(
+                dev.masks[ids], wplane[ids], dev.virtual_to_real[ids], xg,
+                sigma=sigma, impl=impl)                  # (S, C, τ)
+            with jax.named_scope("sssp.update"):
+                rows = dev.row_ids[ids].reshape(-1)
+                return d.at[:, rows].min(y.reshape(S, -1))
+
+        n_chunks = chunk_ceil(count) // PULL_CHUNK
+        with jax.named_scope("sssp.expand"):
+            dist = jax.lax.fori_loop(0, n_chunks, chunk, dist)
+        return dist, (n_chunks * PULL_CHUNK, count, n_chunks)
+
+    return relax
 
 
 def _make_sssp_single(p: BlestProblem, wplane: jnp.ndarray, n_sources: int,
-                      *, use_kernel: bool, buckets: int,
-                      max_rounds: int | None) -> Callable:
-    dev = p.dev
-    n, sigma, n_sets = p.n, p.sigma, p.n_sets
-    S = n_sources
-    ncols = n_sets * sigma
-    widths = queue_widths(p.num_vss, buckets)
-    qcap = widths[-1]
-    compact = make_compactor(dev, p.num_vss, qcap)
-    impl = None if use_kernel else bvss_spmm_minplus_ref
-    valid = jnp.arange(ncols) < n                        # padding columns
-    cap = max_rounds if max_rounds is not None else n + 2
+                      *, impl, cap: int, counters: bool) -> SSSPEngine:
+    n, S = p.n, n_sources
+    ncols = p.n_sets * p.sigma
 
-    def relax(dist: jnp.ndarray, fr: jnp.ndarray
-              ) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """One frontier relaxation: compacted min-plus pull + scatter-min.
-        Returns (new dist, improved mask)."""
-        set_active = fr.reshape(n_sets, sigma, S).any(axis=(1, 2))
-        Q, count = compact(set_active)
-        xg = jnp.where(fr, dist, jnp.inf)                # (ncols, S)
-
-        def pull(w: int) -> jnp.ndarray:
-            ids = jax.lax.slice_in_dim(Q, 0, w)
-            y = bvss_spmm_minplus_local(
-                dev.masks[ids], wplane[ids], dev.virtual_to_real[ids], xg,
-                sigma=sigma, impl=impl)
-            rows = dev.row_ids[ids].reshape(-1)          # dummy = n
-            return dist.at[rows].min(y.reshape(-1, S))
-
-        d2 = select_width(widths, count, pull)
-        # dummy-row scatters may land in padding columns (row n < ncols):
-        # wipe them so the padding never re-enters a gather as a distance
-        d2 = jnp.where(valid[:, None], d2, jnp.inf)
-        return d2, d2 < dist
-
-    def sssp(sources: jnp.ndarray, delta: jnp.ndarray) -> jnp.ndarray:
-        cols = jnp.arange(S)
-        dist = jnp.full((ncols, S), jnp.inf, jnp.float32)
-        dist = dist.at[sources, cols].set(0.0)
-        fr = jnp.zeros((ncols, S), bool).at[sources, cols].set(True)
-        btop = jnp.broadcast_to(delta.astype(jnp.float32), (S,))
+    @jax.named_scope("sssp.bucket")
+    def sssp(arrays, sources, delta):
+        masks, row_ids, v2r, wpl = arrays
+        dev = p.dev._replace(masks=masks, row_ids=row_ids,
+                             virtual_to_real=v2r)
+        relax = _relaxation(dev, wpl, p, S, impl)
+        valid = jnp.arange(ncols) < n                    # padding columns
+        delta = delta.astype(jnp.float32)
+        each = jnp.arange(S)                             # one row a source
+        dist = jnp.full((S, ncols), jnp.inf, jnp.float32)
+        dist = dist.at[each, sources].set(0.0)
+        fr = jnp.zeros((S, ncols), bool).at[each, sources].set(True)
+        btop = jnp.broadcast_to(delta, (S,))
+        tally = jnp.zeros((len(SSSP_COUNTERS),), jnp.int32)
 
         def outer_body(carry):
-            dist, fr, btop, rounds = carry
+            dist, fr, btop, tally = carry
 
             def inner(c):
-                dist, fr, it = c
-                d2, improved = relax(dist, fr)
-                return d2, improved & (d2 < btop[None, :]), it + 1
+                dist, fr, it, tally = c
+                d2, (slots, live, chunks) = relax(
+                    dist, jnp.where(fr, dist, jnp.inf))
+                # dummy-row scatters may land in padding columns (row n <
+                # ncols): wipe them so the padding never re-enters a
+                # gather as a distance
+                d2 = jnp.where(valid, d2, jnp.inf)
+                fr2 = (d2 < dist) & (d2 < btop[:, None])
+                return d2, fr2, it + 1, tally + jnp.stack(
+                    [1, 0, slots, live, chunks])
 
-            dist, fr, _ = jax.lax.while_loop(
+            dist, fr, _, tally = jax.lax.while_loop(
                 lambda c: c[1].any() & (c[2] < cap),
-                inner, (dist, fr, jnp.int32(0)))
-            unsettled = jnp.where(valid[:, None] & (dist >= btop[None, :]),
+                inner, (dist, fr, jnp.int32(0), tally))
+            unsettled = jnp.where(valid & (dist >= btop[:, None]),
                                   dist, jnp.inf)
-            rem = jnp.min(unsettled, axis=0)             # (S,)
-            nbt = _next_btop(rem, btop, delta.astype(jnp.float32))
-            fr = (valid[:, None] & (dist >= btop[None, :])
-                  & (dist < nbt[None, :]))
-            return dist, fr, nbt, rounds + 1
+            rem = jnp.min(unsettled, axis=1)             # (S,)
+            nbt = _next_btop(rem, btop, delta)
+            fr = valid & (dist >= btop[:, None]) & (dist < nbt[:, None])
+            return dist, fr, nbt, tally.at[1].add(1)
 
-        dist, _, _, _ = jax.lax.while_loop(
-            lambda c: c[1].any() & (c[3] < cap),
-            outer_body, (dist, fr, btop, jnp.int32(0)))
-        return dist[:n]
+        dist, _, _, tally = jax.lax.while_loop(
+            lambda c: c[1].any() & (c[3][1] < cap),
+            outer_body, (dist, fr, btop, tally))
+        if counters:
+            return dist[:, :n], tally
+        return dist[:, :n]
 
-    return jax.jit(sssp)
+    return SSSPEngine(jax.jit(sssp), (p.dev.masks, p.dev.row_ids,
+                                      p.dev.virtual_to_real, wplane), S)
 
 
 def _make_sssp_sharded(p: BlestProblem, wplane: jnp.ndarray, n_sources: int,
-                      *, use_kernel: bool, buckets: int,
-                      max_rounds: int | None) -> Callable:
+                       *, impl, cap: int) -> SSSPEngine:
     from jax.sharding import PartitionSpec as P
 
     from repro.core.bvss import ShardedBVSSDevice
     from repro.core.level_pipeline import global_any
-    from repro.distributed.bfs_dist import problem_specs
 
     mesh, axis = p.mesh, p.axis
-    n, sigma, n_sets = p.n, p.sigma, p.n_sets
+    n = p.n
     rps = p.rows_per_shard
     S = n_sources
-    ncols = n_sets * sigma
-    widths = queue_widths(p.num_vss, buckets)
-    qcap = widths[-1]
-    impl = None if use_kernel else bvss_spmm_minplus_ref
-    cap = max_rounds if max_rounds is not None else n + 2
 
-    def local_loop(masks, row_ids, v2r, vstart, vend, wpl, sources, delta):
-        dev = ShardedBVSSDevice(masks[0], row_ids[0], v2r[0],
-                                vstart[0], vend[0])
-        wp = wpl[0]
-        compact = make_compactor(dev, p.num_vss, qcap)
+    def local_loop(masks, row_ids, v2r, wpl, sources, delta):
+        dev = ShardedBVSSDevice(masks[0], row_ids[0], v2r[0], None, None)
+        relax = _relaxation(dev, wpl[0], p, S, impl)
         d = jax.lax.axis_index(axis)
         lvalid = (d * rps + jnp.arange(rps)) < n         # real local rows
         rowmask = jnp.concatenate([lvalid, jnp.zeros((1,), bool)])
         delta32 = delta.astype(jnp.float32)
 
-        def relax(dist: jnp.ndarray, fr: jnp.ndarray
-                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
+        def relax_local(dist: jnp.ndarray, fr: jnp.ndarray):
             # the float twin of the frontier-word gather: every shard
             # needs the frontier's distance VALUES for its global columns
-            # — all-gathered BEFORE the width cond (no collectives in
-            # device-varying branches)
-            xv = jnp.where(fr, dist[:rps], jnp.inf)      # (rps, S)
-            xg = jax.lax.all_gather(xv, axis, tiled=True)  # (D·rps, S)
-            set_active = (xg[:ncols].reshape(n_sets, sigma, S)
-                          < jnp.inf).any(axis=(1, 2))
-            Q, count = compact(set_active)
-
-            def pull(w: int) -> jnp.ndarray:
-                ids = jax.lax.slice_in_dim(Q, 0, w)
-                y = bvss_spmm_minplus_local(
-                    dev.masks[ids], wp[ids], dev.virtual_to_real[ids], xg,
-                    sigma=sigma, impl=impl)
-                rows = dev.row_ids[ids].reshape(-1)      # LOCAL, dummy=rps
-                return dist.at[rows].min(y.reshape(-1, S))
-
-            d2 = select_width(widths, count, pull)
-            d2 = jnp.where(rowmask[:, None], d2, jnp.inf)
+            # — all-gathered BEFORE the chunk loop (no collectives in a
+            # loop whose trip count differs by device)
+            xv = jnp.where(fr, dist[:, :rps], jnp.inf)   # (S, rps)
+            xg = jax.lax.all_gather(xv, axis, axis=1, tiled=True)
+            d2, _ = relax(dist, xg)
+            d2 = jnp.where(rowmask[None, :], d2, jnp.inf)
             return d2, d2 < dist
 
-        def sssp_local(sources: jnp.ndarray) -> jnp.ndarray:
-            cols = jnp.arange(S)
-            lsrc = sources - d * rps
-            own = (lsrc >= 0) & (lsrc < rps)
-            dist = jnp.full((rps + 1, S), jnp.inf, jnp.float32)
-            dist = dist.at[jnp.where(own, lsrc, rps), cols].set(
-                jnp.where(own, 0.0, jnp.inf))
-            fr = jnp.zeros((rps, S), bool).at[
-                jnp.where(own, lsrc, 0), cols].set(own)
-            btop = jnp.broadcast_to(delta32, (S,))
+        each = jnp.arange(S)                             # one row a source
+        lsrc = sources - d * rps
+        own = (lsrc >= 0) & (lsrc < rps)
+        dist = jnp.full((S, rps + 1), jnp.inf, jnp.float32)
+        dist = dist.at[each, jnp.where(own, lsrc, rps)].set(
+            jnp.where(own, 0.0, jnp.inf))
+        fr = jnp.zeros((S, rps), bool).at[
+            each, jnp.where(own, lsrc, 0)].set(own)
+        btop = jnp.broadcast_to(delta32, (S,))
 
-            # the repo's lock-step idiom: while_loop conds read a CARRIED
-            # replicated cont flag; the global_any reduction runs in the
-            # body (never in a cond)
-            def outer_body(carry):
-                dist, fr, btop, cont, rounds = carry
+        # the repo's lock-step idiom: while_loop conds read a CARRIED
+        # replicated cont flag; the global_any reduction runs in the
+        # body (never in a cond)
+        def outer_body(carry):
+            dist, fr, btop, cont, rounds = carry
 
-                def inner(c):
-                    dist, fr, cont, it = c
-                    d2, improved = relax(dist, fr)
-                    fr2 = improved[:rps] & (d2[:rps] < btop[None, :])
-                    return (d2, fr2, global_any(fr2.any(), axis), it + 1)
+            def inner(c):
+                dist, fr, cont, it = c
+                d2, improved = relax_local(dist, fr)
+                fr2 = improved[:, :rps] & (d2[:, :rps] < btop[:, None])
+                return (d2, fr2, global_any(fr2.any(), axis), it + 1)
 
-                dist, fr, _, _ = jax.lax.while_loop(
-                    lambda c: c[2] & (c[3] < cap),
-                    inner, (dist, fr, global_any(fr.any(), axis),
-                            jnp.int32(0)))
-                unsettled = jnp.where(
-                    lvalid[:, None] & (dist[:rps] >= btop[None, :]),
-                    dist[:rps], jnp.inf)
-                rem = jax.lax.pmin(jnp.min(unsettled, axis=0), axis)
-                nbt = _next_btop(rem, btop, delta32)
-                fr = (lvalid[:, None] & (dist[:rps] >= btop[None, :])
-                      & (dist[:rps] < nbt[None, :]))
-                return (dist, fr, nbt, global_any(fr.any(), axis),
-                        rounds + 1)
+            dist, fr, _, _ = jax.lax.while_loop(
+                lambda c: c[2] & (c[3] < cap),
+                inner, (dist, fr, global_any(fr.any(), axis),
+                        jnp.int32(0)))
+            unsettled = jnp.where(
+                lvalid[None, :] & (dist[:, :rps] >= btop[:, None]),
+                dist[:, :rps], jnp.inf)
+            rem = jax.lax.pmin(jnp.min(unsettled, axis=1), axis)
+            nbt = _next_btop(rem, btop, delta32)
+            fr = (lvalid[None, :] & (dist[:, :rps] >= btop[:, None])
+                  & (dist[:, :rps] < nbt[:, None]))
+            return (dist, fr, nbt, global_any(fr.any(), axis), rounds + 1)
 
-            dist, _, _, _, _ = jax.lax.while_loop(
-                lambda c: c[3] & (c[4] < cap),
-                outer_body, (dist, fr, btop, global_any(fr.any(), axis),
-                             jnp.int32(0)))
-            return dist[None, :rps]
-
-        return sssp_local(sources)
+        dist, _, _, _, _ = jax.lax.while_loop(
+            lambda c: c[3] & (c[4] < cap),
+            outer_body, (dist, fr, btop, global_any(fr.any(), axis),
+                         jnp.int32(0)))
+        return dist[:, :rps]
 
     fn = jax.shard_map(local_loop, mesh=mesh,
-                       in_specs=problem_specs(axis) + (P(axis), P(), P()),
-                       out_specs=P(axis), check_vma=False)
+                       in_specs=(P(axis),) * 4 + (P(), P()),
+                       out_specs=P(None, axis), check_vma=False)
 
-    def sssp(sources: jnp.ndarray, delta: jnp.ndarray) -> jnp.ndarray:
-        out = fn(p.dev.masks, p.dev.row_ids, p.dev.virtual_to_real,
-                 p.dev.vss_of_vertex_start, p.dev.vss_of_vertex_end,
-                 wplane, jnp.asarray(sources, jnp.int32),
+    @jax.named_scope("sssp.bucket")
+    def sssp(arrays, sources, delta):
+        out = fn(*arrays, jnp.asarray(sources, jnp.int32),
                  jnp.asarray(delta, jnp.float32))
-        return out.reshape(-1, S)[:p.n]
+        return out[:, :n]
 
-    return jax.jit(sssp)
+    return SSSPEngine(jax.jit(sssp), (p.dev.masks, p.dev.row_ids,
+                                      p.dev.virtual_to_real, wplane), S)
 
 
 def sssp_distances(sources: Sequence[int] | np.ndarray, *,
@@ -298,5 +341,5 @@ def sssp_distances(sources: Sequence[int] | np.ndarray, *,
         dist = np.asarray(sssp_fn(
             jnp.asarray(pad_cohort(chunk, S), dtype=jnp.int32),
             jnp.float32(delta)))
-        out[lo:lo + len(chunk)] = dist.T[:len(chunk)]
+        out[lo:lo + len(chunk)] = dist[:len(chunk)]
     return out

@@ -200,11 +200,13 @@ def build_weight_plane(g: Graph, weights: np.ndarray,
     """Lay per-edge weights out exactly like the BVSS mask bits.
 
     ``weights`` is one float per CSR edge of ``g`` (``g.indices`` order).
-    Returns a (num_vss, 32//σ, LANES, σ) float32 plane where entry
-    ``[v, slot, lane, i]`` is the weight of the edge encoded by bit σ·slot+i
-    of ``masks[v, lane]`` — i.e. the same (slot, lane) slice placement
-    :func:`build_bvss` computes — and +inf wherever that bit is unset (the
-    tropical-semiring annihilator, so masked and missing edges agree).
+    Returns a (num_vss, σ, τ) float32 plane, τ = 32//σ · LANES, where
+    entry ``[v, i, slot·LANES + lane]`` is the weight of the edge encoded
+    by bit σ·slot+i of ``masks[v, lane]`` — i.e. the same (slot, lane)
+    slice placement :func:`build_bvss` computes — and +inf wherever that
+    bit is unset (the tropical-semiring annihilator, so masked and missing
+    edges agree).  The slices are the last, lane dimension: at σ = 8 a
+    VSS's rows are one (8, 128) float32 tile, 4 KiB, with no padding.
     Parallel edges (if any survive ingress) keep the minimum weight.
     """
     if not (1 <= sigma <= 32 and 32 % sigma == 0):
@@ -239,13 +241,10 @@ def build_weight_plane(g: Graph, weights: np.ndarray,
     np.cumsum(set_counts, out=set_starts[1:])
     local = np.arange(num_slices, dtype=np.int64) - set_starts[slice_interval]
     vss = real_ptrs[slice_interval] + local // tau
-    k = local % tau
-    lane = k % LANES
-    slot = k // LANES
+    k = local % tau                          # = slot·LANES + lane
 
-    plane = np.full((num_vss, spw, LANES, sigma), np.inf, dtype=np.float32)
-    np.minimum.at(plane, (vss[inverse], slot[inverse], lane[inverse], bit),
-                  w_t)
+    plane = np.full((num_vss, sigma, tau), np.inf, dtype=np.float32)
+    np.minimum.at(plane, (vss[inverse], bit, k[inverse]), w_t)
     return plane
 
 
@@ -255,7 +254,7 @@ def build_sharded_weight_plane(g: Graph, weights: np.ndarray,
     shard of ``sb``, built over the same destination-range subgraphs
     :func:`build_sharded_bvss` committed (so slice placement matches the
     sharded masks bit for bit), padded to the common VSS count with +inf.
-    Returns (D, num_vss_pad, 32//σ, LANES, σ) float32."""
+    Returns (D, num_vss_pad, σ, τ) float32."""
     from repro.graphs import from_edges, src_of_edges
 
     n = g.n
@@ -265,7 +264,7 @@ def build_sharded_weight_plane(g: Graph, weights: np.ndarray,
     dst = g.indices.astype(np.int64)
     w = np.asarray(weights, dtype=np.float32)
     D, rps = sb.n_shards, sb.rows_per_shard
-    plane = np.full((D, sb.num_vss_pad, spw, LANES, sigma), np.inf,
+    plane = np.full((D, sb.num_vss_pad, sigma, spw * LANES), np.inf,
                     dtype=np.float32)
     for d in range(D):
         lo, hi = d * rps, min((d + 1) * rps, n)
@@ -293,12 +292,12 @@ def weight_plane_to_device(plane: np.ndarray, mesh=None, axis: str = "data"):
     import jax
     import jax.numpy as jnp
 
-    if plane.ndim == 4:                       # single device: (V, spw, L, σ)
+    if plane.ndim == 3:                       # single device: (V, σ, τ)
         full = np.concatenate(
             [plane, np.full((1,) + plane.shape[1:], np.inf, np.float32)],
             axis=0)
         return jnp.asarray(full)
-    D = plane.shape[0]                        # sharded: (D, V, spw, L, σ)
+    D = plane.shape[0]                        # sharded: (D, V, σ, τ)
     full = np.concatenate(
         [plane, np.full((D, 1) + plane.shape[2:], np.inf, np.float32)],
         axis=1)

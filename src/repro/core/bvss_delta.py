@@ -112,18 +112,17 @@ def _encode_set(slices: dict[int, int], n_vss: int, sigma: int,
 def _weight_rows(slices: dict[int, int], n_vss: int, sigma: int,
                  set_id: int, weight_of) -> np.ndarray:
     """The touched set's weight-plane rows under the same packing:
-    entry ``[v, slot, lane, b]`` = weight of edge ``σ·set_id + b -> row``,
-    +inf where the mask bit is unset (the tropical annihilator)."""
-    spw = 32 // sigma
-    tau = LANES * spw
-    plane = np.full((n_vss, spw, LANES, sigma), np.inf, dtype=np.float32)
+    entry ``[v, b, slot·LANES + lane]`` = weight of edge
+    ``σ·set_id + b -> row``, +inf where the mask bit is unset (the
+    tropical annihilator)."""
+    tau = LANES * (32 // sigma)
+    plane = np.full((n_vss, sigma, tau), np.inf, dtype=np.float32)
     for k, row in enumerate(sorted(slices)):
         v, kk = k // tau, k % tau
-        lane, slot = kk % LANES, kk // LANES
         m = slices[row]
         for b in range(sigma):
             if (m >> b) & 1:
-                plane[v, slot, lane, b] = weight_of(set_id * sigma + b, row)
+                plane[v, b, kk] = weight_of(set_id * sigma + b, row)
     return plane
 
 
@@ -421,7 +420,7 @@ def _patch(prepared: PreparedBFS, g_ord2: Graph, w_new, opts: PrepareOptions,
 
 def _patch_wplane_single(wplane, b: BVSS, sets, g_ord2: Graph, weight_of):
     """Scatter recomputed weight-plane rows for the touched sets
-    (single-device plane: (num_vss + 1, spw, LANES, σ), +inf dummy last)."""
+    (single-device plane: (num_vss + 1, σ, τ), +inf dummy last)."""
     n, sigma = b.n, b.sigma
     src_t, dst_t = _edges_of_sets(g_ord2, sets, sigma)
     for I in sets:

@@ -263,7 +263,11 @@ def prepare(g: Graph, options: PrepareOptions | None = None,
         with tracing.span("repro.prepare.ordering"):
             perm, kind, g_ord, inv, w_ord = _order(g, o)
         with tracing.span("repro.prepare.bvss"):
-            bvss, engine_name, problem, wplane = _build_bvss(g_ord, w_ord, o)
+            bvss, engine_name, problem, sb = _build_bvss(g_ord, w_ord, o)
+        wplane = None
+        if w_ord is not None:
+            with tracing.span("repro.prepare.weights"):
+                wplane = _build_weight_plane(g_ord, w_ord, sb, o)
         with tracing.span("repro.prepare.engine"):
             tile_config: TileConfig | None = None
             tuned_kwargs: dict = {}
@@ -309,11 +313,13 @@ def _order(g: Graph, o: PrepareOptions):
 
 def _build_bvss(g_ord: Graph, w_ord, o: PrepareOptions):
     """The BVSS stage: the host BVSS, the update scheme and the device
-    problem and weight plane, ``(bvss, engine_name, problem, wplane)``."""
+    problem, ``(bvss, engine_name, problem, sb)``; ``sb`` is the sharded
+    BVSS a weighted 1-D mesh problem was built from (else None), which
+    its weight plane must align with."""
     bvss = build_bvss(g_ord, sigma=o.sigma)
     engine_name = o.engine if o.engine is not None else \
         choose_update_scheme(bvss, threshold=o.lazy_threshold)
-    wplane = None
+    sb = None
     if o.mesh is not None:
         if engine_name not in BVSS_ENGINES:
             raise ValueError(
@@ -332,9 +338,6 @@ def _build_bvss(g_ord: Graph, w_ord, o: PrepareOptions):
             sb = build_sharded_bvss(g_ord, o.mesh.shape[o.mesh_axis],
                                     sigma=o.sigma)
             problem = BlestProblem.build_sharded(sb, o.mesh, o.mesh_axis)
-            wplane = weight_plane_to_device(
-                build_sharded_weight_plane(g_ord, w_ord, sb), o.mesh,
-                o.mesh_axis)
         else:
             problem = build_problem(g_ord, sigma=o.sigma, mesh=o.mesh,
                                     mesh_axis=o.mesh_axis)
@@ -343,10 +346,18 @@ def _build_bvss(g_ord: Graph, w_ord, o: PrepareOptions):
         # the host bvss alone backs the stats printouts and the policy
         problem = BlestProblem.build(bvss) if engine_name in BVSS_ENGINES \
             else None
-        if w_ord is not None:
-            wplane = weight_plane_to_device(
-                build_weight_plane(g_ord, w_ord, sigma=o.sigma))
-    return bvss, engine_name, problem, wplane
+    return bvss, engine_name, problem, sb
+
+
+def _build_weight_plane(g_ord: Graph, w_ord, sb, o: PrepareOptions):
+    """The weight-plane stage: the plane in the BVSS slice layout (per
+    shard of ``sb`` on a 1-D mesh), committed to the device."""
+    if sb is not None:
+        return weight_plane_to_device(
+            build_sharded_weight_plane(g_ord, w_ord, sb), o.mesh,
+            o.mesh_axis)
+    return weight_plane_to_device(
+        build_weight_plane(g_ord, w_ord, sigma=o.sigma))
 
 
 def build_engine(g_ord: Graph, engine_name: str, bvss: BVSS, problem,

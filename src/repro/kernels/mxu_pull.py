@@ -371,90 +371,90 @@ def bvss_spmm_w_local(masks: jnp.ndarray, sets: jnp.ndarray,
 # min-plus BVSS tiles: the tropical semiring (SSSP relaxation, DESIGN §2.9)
 # ---------------------------------------------------------------------------
 def _bvss_spmm_minplus_kernel(masks_ref, wv_ref, xv_ref, y_ref, *,
-                              sigma: int):
-    """masks_ref (TB, 32) u32; wv_ref (TB, τ, σ) f32 edge weights (+inf on
-    non-edges is also enforced here via the mask bits); xv_ref (TB, σ, TS)
-    f32 per-column distances; y_ref (TB, τ, TS) f32 tropical product
+                              sigma: int, tile_b: int):
+    """masks_ref (TB, 32) u32; wv_ref (TB·σ, τ) f32 weight-plane rows, row
+    b·σ + i holding the weights of column i into the τ slices of VSS b
+    (+inf on non-edges, also enforced here via the mask bits); xv_ref
+    (1, σ, TB) f32 the distances of each VSS's σ columns for one source;
+    y_ref (1, TB, τ) f32 the tropical product
 
-        y[b, k, s] = min_i ( w[b, k, i] + x[b, i, s] )   over set bits i.
+        y[b, k] = min_i ( w[b, i, k] + x[i, b] )   over set bits i.
 
-    σ is tiny (≤32), so the contraction is an unrolled elementwise min —
-    no dot_general exists for (min, +), and with +inf as the annihilator
-    the expression never forms inf − inf, so no NaNs leak out."""
-    a = _slice_bits(masks_ref[...], sigma)                   # (TB, τ, σ)
+    Every block is lane-dense: τ or the batch fills the lanes.  Row i of
+    each VSS is a strided read of the plane block, and the distances move
+    from lanes to sublanes in one transpose.  σ is tiny (≤ 32), so the
+    contraction is an unrolled elementwise min — no dot_general exists
+    for (min, +) — and with +inf as the annihilator the expression never
+    forms inf − inf, so no NaNs leak out."""
+    spw = 32 // sigma
+    m = masks_ref[...]
+    if spw > 1:
+        m = jnp.concatenate([m] * spw, axis=1)      # (TB, τ): word k % 32
+    k = jax.lax.broadcasted_iota(jnp.uint32, (1, spw * 32), 1)
+    shift = (k // 32) * jnp.uint32(sigma)           # bit σ·slot of slice k
+    xt = xv_ref[0].T                                # (TB, σ)
     inf = jnp.float32(jnp.inf)
-    w = jnp.where(a > 0, wv_ref[...], inf)                   # (TB, τ, σ)
-    x = xv_ref[...]
-    acc = jnp.full(y_ref.shape, inf, dtype=jnp.float32)
-    for i in range(sigma):   # static slices keep the tile 3-D (no gather)
-        acc = jnp.minimum(acc, w[:, :, i:i + 1] + x[:, i:i + 1, :])
-    y_ref[...] = acc
+    acc = jnp.full((tile_b, spw * 32), inf, dtype=jnp.float32)
+    for i in range(sigma):
+        w = wv_ref[pl.ds(i, tile_b, stride=sigma), :]           # (TB, τ)
+        bit = (m >> (shift + jnp.uint32(i))) & jnp.uint32(1)
+        acc = jnp.minimum(acc, jnp.where(bit > 0, w + xt[:, i:i + 1], inf))
+    y_ref[0] = acc
 
 
-def _spmm_minplus_call(masks, wvals, xvals, *, sigma: int,
-                       tile_b: int | None, tile_s: int | None,
-                       interpret: bool | None):
-    """pallas_call plumbing for the three-operand tropical tile product:
-    the `_spmm_float_call` layout plus a (B, τ, σ) weight plane operand."""
-    from repro.kernels.ops import resolve_interpret
-    interpret = resolve_interpret(interpret)
-    B, S = masks.shape[0], xvals.shape[2]
-    tau = (32 // sigma) * 32
-    if tile_b is None:
-        tile_b = 128 if interpret else 8
-    if tile_s is None:
-        tile_s = min(128, ((S + 7) // 8) * 8)
-    pb, ps = (-B) % tile_b, (-S) % tile_s
-    if pb:
-        masks = jnp.pad(masks, ((0, pb), (0, 0)))
-        wvals = jnp.pad(wvals, ((0, pb), (0, 0), (0, 0)))
-        xvals = jnp.pad(xvals, ((0, pb), (0, 0), (0, 0)))
-    if ps:
-        xvals = jnp.pad(xvals, ((0, 0), (0, 0), (0, ps)))
-    Bp, Sp = B + pb, S + ps
-    grid = (Bp // tile_b, Sp // tile_s)
-    y = pl.pallas_call(
-        functools.partial(_bvss_spmm_minplus_kernel, sigma=sigma),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_b, 32), lambda b, s: (b, 0)),
-            pl.BlockSpec((tile_b, tau, sigma), lambda b, s: (b, 0, 0)),
-            pl.BlockSpec((tile_b, sigma, tile_s), lambda b, s: (b, 0, s)),
-        ],
-        out_specs=pl.BlockSpec((tile_b, tau, tile_s),
-                               lambda b, s: (b, 0, s)),
-        out_shape=jax.ShapeDtypeStruct((Bp, tau, Sp), jnp.float32),
-        interpret=interpret,
-        name="bvss_spmm_minplus",
-    )(masks, wvals, xvals)
-    return y[:B, :, :S]
-
-
-@functools.partial(jax.jit, static_argnames=("sigma", "tile_b", "tile_s",
+@functools.partial(jax.jit, static_argnames=("sigma", "tile_b",
                                              "interpret"))
 def bvss_spmm_minplus(masks: jnp.ndarray, wvals: jnp.ndarray,
                       xvals: jnp.ndarray, *, sigma: int = 8,
-                      tile_b: int | None = None, tile_s: int | None = None,
+                      tile_b: int | None = None,
                       interpret: bool | None = None) -> jnp.ndarray:
     """Tropical (min, +) BVSS pull: the SSSP relaxation tile (DESIGN §2.9).
 
     masks: (B, 32) uint32 queued VSS mask rows.
-    wvals: (B, spw, 32, σ) float32 — the weight plane rows of the queued
-           VSS (``build_weight_plane`` layout: +inf where no edge), laid
-           out exactly like ``row_ids`` with the σ slice-set column last.
-    xvals: (B, σ, S) float32 per-column tentative distances (+inf for
-           unreached columns — the tropical zero).
-    returns (B, spw, 32, S) float32; [b, j, l, s] is
-           min over in-neighbour columns i of (w[v→row] + dist[v]) for
-           slice k = j*32 + l — scatter-``min`` it into rows via
-           ``row_ids`` (the edge-relaxation recurrence).
+    wvals: (B, σ, τ) float32 — the weight plane rows of the queued VSSs
+           (``build_weight_plane`` layout: +inf where no edge), slice
+           k = j*32 + l last, in the lanes.
+    xvals: (S, σ, B) float32 per-column tentative distances, one row per
+           source and the batch last (+inf for unreached columns — the
+           tropical zero).
+    returns (S, B, τ) float32; [s, b, k] is the min over the in-neighbour
+           columns i of (w[v→row] + dist[v]) for slice k of VSS b —
+           scatter-``min`` it into rows via ``row_ids`` (the
+           edge-relaxation recurrence).
+
+    No operand or result has σ or S as its minor dimension, so nothing
+    is padded to the 128 lanes: at σ = 8 a VSS's plane rows are one
+    (8, 128) float32 tile, 4 KiB.  The batch tile is 128 VSSs (the lanes
+    of the distance block); the grid runs the sources innermost, so each
+    plane block is read once for all of them.
     """
-    spw = 32 // sigma
-    B = masks.shape[0]
-    wv = wvals.reshape(B, spw * 32, sigma)
-    y = _spmm_minplus_call(masks, wv, xvals, sigma=sigma, tile_b=tile_b,
-                           tile_s=tile_s, interpret=interpret)
-    return y.reshape(B, spw, 32, y.shape[2])
+    from repro.kernels.ops import resolve_interpret
+    interpret = resolve_interpret(interpret)
+    B, S = masks.shape[0], xvals.shape[0]
+    tau = (32 // sigma) * 32
+    if tile_b is None:
+        tile_b = 128
+    pb = (-B) % tile_b
+    if pb:
+        masks = jnp.pad(masks, ((0, pb), (0, 0)))
+        wvals = jnp.pad(wvals, ((0, pb), (0, 0), (0, 0)))
+        xvals = jnp.pad(xvals, ((0, 0), (0, 0), (0, pb)))
+    Bp = B + pb
+    y = pl.pallas_call(
+        functools.partial(_bvss_spmm_minplus_kernel, sigma=sigma,
+                          tile_b=tile_b),
+        grid=(Bp // tile_b, S),
+        in_specs=[
+            pl.BlockSpec((tile_b, 32), lambda b, s: (b, 0)),
+            pl.BlockSpec((tile_b * sigma, tau), lambda b, s: (b, 0)),
+            pl.BlockSpec((1, sigma, tile_b), lambda b, s: (s, 0, b)),
+        ],
+        out_specs=pl.BlockSpec((1, tile_b, tau), lambda b, s: (s, b, 0)),
+        out_shape=jax.ShapeDtypeStruct((S, Bp, tau), jnp.float32),
+        interpret=interpret,
+        name="bvss_spmm_minplus",
+    )(masks, wvals.reshape(Bp * sigma, tau), xvals)
+    return y[:, :B]
 
 
 def bvss_spmm_minplus_local(masks: jnp.ndarray, wvals: jnp.ndarray,
@@ -462,19 +462,20 @@ def bvss_spmm_minplus_local(masks: jnp.ndarray, wvals: jnp.ndarray,
                             sigma: int = 8, impl=None) -> jnp.ndarray:
     """Min-plus pull of a queued VSS batch against a GLOBAL column-distance
     array — the tropical twin of ``bvss_spmm_w_local``: gathers each VSS's
-    (σ, S) slice-set distance block out of ``xglobal`` (single-device: the
-    padded distance vector; row-sharded: the per-wave all-gather of every
-    shard's local distances) and relaxes it through the (τ, σ) weight tile.
+    σ slice-set distances per source out of ``xglobal`` (single-device:
+    the padded distance rows; row-sharded: the per-round all-gather of
+    every shard's local distances) and relaxes them through the (σ, τ)
+    weight tile.
 
-    masks: (B, 32) u32 queued mask rows; wvals: (B, spw, 32, σ) f32 queued
+    masks: (B, 32) u32 queued mask rows; wvals: (B, σ, τ) f32 queued
     weight-plane rows (``wplane[Q]``); sets: (B,) int32 GLOBAL slice-set
-    ids; xglobal: (C, S) f32, C ≥ n_sets·σ.  Returns (B, spw, 32, S) f32 —
+    ids; xglobal: (S, C) f32, C ≥ n_sets·σ.  Returns (S, B, τ) f32 —
     scatter-``min`` into (local) rows via ``row_ids``.
     """
-    cols = (sets[:, None] * sigma
-            + jnp.arange(sigma, dtype=jnp.int32)[None, :])      # (B, σ)
+    cols = (sets[None, :] * sigma
+            + jnp.arange(sigma, dtype=jnp.int32)[:, None])      # (σ, B)
     f = bvss_spmm_minplus if impl is None else impl
-    return f(masks, wvals, xglobal[cols], sigma=sigma)
+    return f(masks, wvals, xglobal[:, cols], sigma=sigma)
 
 
 def bvss_spmm_t_local(masks: jnp.ndarray, row_ids: jnp.ndarray,

@@ -75,12 +75,15 @@ def bvss_spmm_w_ref(masks: jnp.ndarray, xvals: jnp.ndarray, sigma: int = 8
 
 def bvss_spmm_minplus_ref(masks: jnp.ndarray, wvals: jnp.ndarray,
                           xvals: jnp.ndarray, sigma: int = 8) -> jnp.ndarray:
-    """Oracle for kernels.bvss_spmm_minplus: (B, 32/σ, 32, S) float32
-    tropical pulls — per slice, the min over its masked σ columns of
-    (edge weight + column distance), +inf where the slice has no edge."""
-    a = _abits(masks, sigma)                             # (B, spw, 32, σ)
-    w = jnp.where(a > 0, wvals, jnp.inf)                 # (B, spw, 32, σ)
-    return jnp.min(w[..., None] + xvals[:, None, None, :, :], axis=3)
+    """Oracle for kernels.bvss_spmm_minplus: (S, B, τ) float32 tropical
+    pulls of (B, σ, τ) plane rows against (S, σ, B) column distances —
+    per slice, the min over its masked σ columns of (edge weight + column
+    distance), +inf where the slice has no edge."""
+    B = masks.shape[0]
+    a = _abits(masks, sigma).reshape(B, -1, sigma)       # (B, τ, σ)
+    w = jnp.where(jnp.swapaxes(a, 1, 2) > 0, wvals, jnp.inf)   # (B, σ, τ)
+    x = jnp.swapaxes(xvals, 1, 2)[..., None]             # (S, B, σ, 1)
+    return jnp.min(w[None] + x, axis=2)
 
 
 def bvss_spmm_t_ref(masks: jnp.ndarray, hvals: jnp.ndarray, sigma: int = 8

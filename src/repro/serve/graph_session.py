@@ -47,15 +47,17 @@ import warnings
 from collections import deque
 from typing import Sequence
 
+import jax
 import numpy as np
 from jax.sharding import Mesh
 
 from repro import tracing
 from repro.analytics import (ExtremesReport, betweenness_centrality,
                              closeness_centrality, connected_components,
-                             eccentricities, ifub_extremes, make_pagerank,
-                             make_sssp, out_degrees, pagerank_scores,
-                             sssp_distances)
+                             default_delta, eccentricities, ifub_extremes,
+                             make_pagerank, make_sssp, out_degrees,
+                             pagerank_scores, sssp_distances)
+from repro.analytics.sssp import SSSP_COUNTERS
 from repro.core.bfs import BlestProblem
 from repro.core.multi_source import drive_wave, make_ms_engine
 from repro.core.policy import PreparedBFS, PrepareOptions, prepare
@@ -521,14 +523,28 @@ class GraphSession:
             self._analytics_cache["unit_wplane"] = plane
         return self._analytics_cache["unit_wplane"]
 
-    def _sssp_fn(self, width: int):
+    def _delta(self) -> float:
+        """The session's bucket width Δ: the mean of its arc weights,
+        computed once per epoch (DESIGN §2.9)."""
+        if "delta" not in self._analytics_cache:
+            self._analytics_cache["delta"] = default_delta(
+                self._weights_ord())
+        return self._analytics_cache["delta"]
+
+    def _sssp_fn(self, width: int, *, counted: bool = False):
         """Cached delta-stepping engine of cohort width ``width`` on the
-        session's own (possibly sharded) problem."""
-        key = ("sssp_fn", width)
+        session's own (possibly sharded) problem.  ``counted``: the
+        single-device engine with its counters, compiled on first use and
+        its device operations mapped to their stages by the tracer."""
+        counted = counted and self._problem.mesh is None
+        key = ("sssp_fn", width, counted)
         if key not in self._analytics_cache:
-            self._analytics_cache[key] = make_sssp(
-                self._problem, self._wplane(), width,
-                use_kernel=self._use_kernel)
+            fn = make_sssp(self._problem, self._wplane(), width,
+                           use_kernel=self._use_kernel, counters=counted)
+            if counted:
+                fn = fn.compile()
+                tracing.map_stages(fn.fn.as_text())
+            self._analytics_cache[key] = fn
         return self._analytics_cache[key]
 
     def sssp(self, src: int, *, delta: float | None = None) -> np.ndarray:
@@ -537,14 +553,26 @@ class GraphSession:
         unreachable.  Delta-stepping over the min-plus tile product
         against the session's weight plane (unit weights on an
         unweighted session, where this equals BFS hop counts).  ``delta``
-        overrides the bucket width (performance only, never
-        correctness)."""
-        src = check_source(src, self.n)
-        dist = sssp_distances(
-            [int(self.perm[src])], problem=self._problem,
-            wplane=self._wplane(), weights=self._weights_ord(),
-            batch=1, delta=delta, sssp_fn=self._sssp_fn(1))
-        return dist[0][self.perm]
+        overrides the session's bucket width (performance only, never
+        correctness).  Traced as one query, with the engine's counters
+        as ``sssp.<name>`` (single device)."""
+        with tracing.query():
+            src = check_source(src, self.n)
+            fn = self._sssp_fn(1, counted=True)
+            d = self._delta() if delta is None else delta
+            with tracing.span("repro.query.dispatch"):
+                out = fn(np.asarray([self.perm[src]], np.int32),
+                         np.float32(d))
+            with tracing.span("repro.query.wait"):
+                out = jax.block_until_ready(out)
+            with tracing.span("repro.query.copy"):
+                out = jax.device_get(out)
+            if isinstance(out, tuple):
+                out, counts = out
+                tracing.record({"sssp." + k: v for k, v in
+                                zip(SSSP_COUNTERS, counts.tolist())})
+            with tracing.span("repro.query.unpermute"):
+                return out[0].astype(np.float64)[self.perm]
 
     def sssp_batch(self, sources: Sequence[int], *,
                    delta: float | None = None) -> np.ndarray:
@@ -558,7 +586,8 @@ class GraphSession:
         width = min(self.max_batch, len(srcs))
         dist = sssp_distances(
             self.perm[srcs], problem=self._problem, wplane=self._wplane(),
-            weights=self._weights_ord(), batch=width, delta=delta,
+            weights=self._weights_ord(), batch=width,
+            delta=self._delta() if delta is None else delta,
             sssp_fn=self._sssp_fn(width))
         return dist[:, self.perm]
 

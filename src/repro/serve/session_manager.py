@@ -56,7 +56,7 @@ from jax.sharding import Mesh
 from repro import tracing
 from repro.core import reference_bfs
 from repro.errors import (AdmissionError, DeadlineExceeded,
-                          KernelFaultError, check_sources)
+                          KernelFaultError, check_source, check_sources)
 from repro.graphs import Graph
 from repro.kernels.ref import betweenness_ref, closeness_ref
 from repro.serve.graph_session import GraphSession
@@ -466,6 +466,24 @@ class GraphSessionManager:
             f"({rec.quarantine_reason}); serving {len(srcs)} queries on "
             f"the reference path", DegradedServiceWarning, stacklevel=3)
         return [reference_bfs(rec.graph, s) for s in srcs]
+
+    # ------------------------------------------------------------------
+    # weighted shortest paths (DESIGN §2.9)
+    # ------------------------------------------------------------------
+    def sssp(self, name: str, src: int, *, tenant: str = "default"
+             ) -> np.ndarray:
+        """Single-source shortest-path distances on session ``name``:
+        caller-id float64 distances, ``+inf`` where unreached, as
+        :meth:`GraphSession.sssp` gives them (the session's own weights,
+        its bucket width computed once).  Traced as one ``repro.query``
+        with its admission, dispatch, wait, copy and unpermute spans and
+        the engine's ``sssp.*`` counters."""
+        with tracing.query():
+            with tracing.span("repro.query.admit"):
+                rec = self._get(name, tenant)
+                src = check_source(src, rec.session.n)
+            rec.served += 1
+            return rec.session.sssp(src)
 
     # ------------------------------------------------------------------
     # analytics with the finite guard

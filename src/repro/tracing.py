@@ -23,9 +23,9 @@ runs the same code whether or not a profiler records it.
   to.  Only that small map is kept.
 
 Span names used by the program: ``repro.prepare`` (children
-``repro.prepare.ordering``, ``.bvss``, ``.engine``) and ``repro.query``
-(children ``repro.query.admit``, ``.dispatch``, ``.wait``, ``.copy``,
-``.unpermute``, ``.verify``).
+``repro.prepare.ordering``, ``.bvss``, ``.weights`` on a weighted graph,
+``.engine``) and ``repro.query`` (children ``repro.query.admit``,
+``.dispatch``, ``.wait``, ``.copy``, ``.unpermute``, ``.verify``).
 """
 from __future__ import annotations
 
@@ -43,7 +43,11 @@ import jax
 #: closed spans kept in the ring
 CAPACITY = 65536
 #: the named device stages of the BFS level step, as ``jax.named_scope``
-STAGES = ("bfs.expand", "bfs.update", "bfs.finalize")
+BFS_STAGES = ("bfs.expand", "bfs.update", "bfs.finalize")
+#: those of the delta-stepping SSSP engine: gather and min-plus pull,
+#: scatter-min, and the settle sweep / bucket advance / next frontier
+SSSP_STAGES = ("sssp.expand", "sssp.update", "sssp.bucket")
+STAGES = BFS_STAGES + SSSP_STAGES
 QUERY = "repro.query"
 
 

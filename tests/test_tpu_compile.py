@@ -70,7 +70,12 @@ CASES = {
     "bvss_spmm_minplus": (
         lambda m, w, x: K.bvss_spmm_minplus(m, w, x, sigma=SIGMA,
                                             interpret=False),
-        [((B, 32), U32), ((B, SPW, 32, SIGMA), F32), ((B, SIGMA, S), F32)]),
+        [((B, 32), U32), ((B, SIGMA, SPW * 32), F32), ((S, SIGMA, B), F32)]),
+    "bvss_spmm_minplus[chunk,S=1]": (
+        lambda m, w, x: K.bvss_spmm_minplus(m, w, x, sigma=SIGMA,
+                                            interpret=False),
+        [((PULL_CHUNK, 32), U32), ((PULL_CHUNK, SIGMA, SPW * 32), F32),
+         ((1, SIGMA, PULL_CHUNK), F32)]),
     "bvss_spmm_w_local": (
         lambda m, sets, xg: K.bvss_spmm_w_local(
             m, sets, xg, sigma=SIGMA,
@@ -86,8 +91,8 @@ CASES = {
             m, w, sets, xg, sigma=SIGMA,
             impl=lambda *a, **k: K.bvss_spmm_minplus(*a, **k,
                                                      interpret=False)),
-        [((B, 32), U32), ((B, SPW, 32, SIGMA), F32), ((B,), I32),
-         ((N, S), F32)]),
+        [((B, 32), U32), ((B, SIGMA, SPW * 32), F32), ((B,), I32),
+         ((S, N), F32)]),
 }
 
 
@@ -146,4 +151,51 @@ def test_engine_stages_compile_for_v5e(one_chip, monkeypatch, lazy):
            if i.opcode in ("fusion", "custom-call")]
     kernels = [i for i in ops if i.opcode == "custom-call"]
     assert kernels and "tpu_custom_call" in text
-    assert all(stages.get(i.name) in tracing.STAGES for i in ops)
+    assert all(stages.get(i.name) in tracing.BFS_STAGES for i in ops)
+
+
+def test_sssp_engine_compiles_lane_dense_for_v5e(one_chip, monkeypatch):
+    """The counted delta-stepping engine compiles for the chip with its
+    min-plus kernel; every fusion and kernel of its loops maps to one of
+    its three named stages, and no float array it holds (plane rows,
+    gathers, kernel operands and results, distances) of more than one
+    element has σ or 1 as its minor dimension."""
+    import re
+
+    import numpy as np
+
+    from repro import tracing
+    from repro.analytics.sssp import make_sssp
+    from repro.core.bfs import BlestProblem
+    from repro.core.bvss import build_bvss, build_weight_plane
+    from repro.graphs import generators as gen
+
+    monkeypatch.setenv("BLEST_INTERPRET", "0")
+    # the kernel's jit cache keys on shapes alone: a CPU test of the
+    # engine at the chunk width may have traced it in interpret mode
+    jax.clear_caches()
+    g = gen.rmat(10, 16, seed=1)
+    p = BlestProblem.build(build_bvss(g))
+    plane = build_weight_plane(g, np.ones(g.m, np.float32))
+    wplane = jax.ShapeDtypeStruct((plane.shape[0] + 1,) + plane.shape[1:],
+                                  F32, sharding=one_chip)
+    eng = make_sssp(p, wplane, 1, counters=True)
+    arrays = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                   for a in eng.arrays)
+    text = eng._replace(arrays=arrays).compile().fn.as_text()
+    assert "tpu_custom_call" in text
+    stages = tracing.stage_map(text)
+    comps, entry = tracing.parse_hlo(text)
+    loop = [n for i in comps[entry] if i.opcode == "while"
+            for c in i.control for n in tracing.scheduled(comps, c)]
+    ops = [i for n in loop for i in comps[n]
+           if i.opcode in ("fusion", "custom-call")]
+    assert ops and all(stages.get(i.name) in tracing.SSSP_STAGES
+                       for i in ops)
+    floats = re.findall(r"f32\[([\d,]+)\]\{([\d,]+)", text)
+    assert any(d.split(",")[-1] == "128" for d, _ in floats)
+    for dims, layout in floats:
+        d = [int(x) for x in dims.split(",")]
+        minor = d[int(layout.split(",")[0])]
+        assert len(d) < 2 or np.prod(d) == 1 or minor not in (SIGMA, 1), \
+            f"f32[{dims}]{{{layout}}}"

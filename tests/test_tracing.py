@@ -134,8 +134,8 @@ def test_every_loop_operation_has_a_stage(lazy, use_kernels):
     text = fn.lower(jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
     stages = tracing.stage_map(text)
     ops = _loop_ops(text)
-    assert ops and all(stages.get(i.name) in tracing.STAGES for i in ops)
-    assert set(stages.values()) == set(tracing.STAGES)
+    assert ops and all(stages.get(i.name) in tracing.BFS_STAGES for i in ops)
+    assert set(stages.values()) == set(tracing.BFS_STAGES)
 
 
 def test_stage_map_reads_scopes_fusions_and_neighbours():

@@ -34,21 +34,38 @@ def assert_dist_equal(dist, ref):
 # ---------------------------------------------------------------------------
 # min-plus tile kernel vs reference
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("sigma", [8, 4])
+def _minplus_brute(masks, wv, xv, sigma):
+    """(S, B, τ): per slice k of VSS b, the min over the set mask bits i
+    of its word k % 32 of w[b, i, k] + x[s, i, b], loop by loop."""
+    S, B, tau = xv.shape[0], masks.shape[0], wv.shape[2]
+    out = np.full((S, B, tau), np.inf, dtype=np.float32)
+    for b in range(B):
+        for k in range(tau):
+            word = int(masks[b, k % 32])
+            for i in range(sigma):
+                if (word >> ((k // 32) * sigma + i)) & 1:
+                    out[:, b, k] = np.minimum(out[:, b, k],
+                                              wv[b, i, k] + xv[:, i, b])
+    return out
+
+
+@pytest.mark.parametrize("sigma", [8, 4, 1, 2, 16, 32])
 def test_minplus_kernel_matches_ref(sigma):
     from repro.kernels import bvss_spmm_minplus
     from repro.kernels.ref import bvss_spmm_minplus_ref
     rng = np.random.default_rng(0)
     B, S = 6, 5
-    spw = 32 // sigma
+    tau = (32 // sigma) * 32
     masks = rng.integers(0, 2**32, (B, 32), dtype=np.uint64) \
                .astype(np.uint32)
-    wv = rng.uniform(0.5, 4.0, (B, spw, 32, sigma)).astype(np.float32)
-    xv = rng.uniform(0.0, 9.0, (B, sigma, S)).astype(np.float32)
-    xv[rng.random((B, sigma, S)) < 0.3] = np.inf   # inactive columns
+    wv = rng.uniform(0.5, 4.0, (B, sigma, tau)).astype(np.float32)
+    xv = rng.uniform(0.0, 9.0, (S, sigma, B)).astype(np.float32)
+    xv[rng.random((S, sigma, B)) < 0.3] = np.inf   # inactive columns
     got = np.asarray(bvss_spmm_minplus(masks, wv, xv, sigma=sigma))
     want = np.asarray(bvss_spmm_minplus_ref(masks, wv, xv, sigma=sigma))
+    assert got.shape == (S, B, tau)
     np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(want, _minplus_brute(masks, wv, xv, sigma))
     assert not np.isnan(got).any()
 
 
@@ -57,9 +74,10 @@ def test_minplus_all_inf_columns_yield_inf():
     rng = np.random.default_rng(1)
     masks = rng.integers(0, 2**32, (3, 32), dtype=np.uint64) \
                .astype(np.uint32)
-    wv = rng.uniform(0.5, 4.0, (3, 4, 32, 8)).astype(np.float32)
-    xv = np.full((3, 8, 2), np.inf, dtype=np.float32)
+    wv = rng.uniform(0.5, 4.0, (3, 8, 128)).astype(np.float32)
+    xv = np.full((2, 8, 3), np.inf, dtype=np.float32)
     out = np.asarray(bvss_spmm_minplus(masks, wv, xv, sigma=8))
+    assert out.shape == (2, 3, 128)
     assert np.isinf(out).all() and not np.isnan(out).any()
 
 
