@@ -16,9 +16,11 @@ driver is a bucketed label-correcting loop — batched delta-stepping:
   live VSS count needs (the single-source BFS pull's loop, DESIGN §2.3):
   each chunk gathers its masks, weight-plane rows and σ-column distances,
   runs the min-plus tile product and scatter-``min``s into the distance
-  rows.  The pull reads only the frontier's distances, which no chunk
-  changes, and the scatter-min is idempotent, so the chunks give the
-  one-shot relaxation exactly;
+  rows (the Pallas ``scatter_min``, which walks the chunk's live pairs
+  against distances held in VMEM, where they fit it).  The pull
+  reads only the frontier's distances, which no chunk changes, and the
+  scatter-min is idempotent, so the chunks give the one-shot relaxation
+  exactly;
 * the bucket advance jumps ``btop`` to the bucket holding the smallest
   unsettled distance — empty buckets cost nothing, so the classic Δ
   trade-off (bucket width vs relaxation rounds) only shapes performance,
@@ -55,15 +57,18 @@ from repro.analytics.common import pad_cohort
 from repro.core.bfs import (PULL_CHUNK, BlestProblem, chunk_ceil,
                             make_compactor)
 from repro.errors import ConfigError
-from repro.kernels import bvss_spmm_minplus_local
-from repro.kernels.ref import bvss_spmm_minplus_ref
+from repro.kernels import bvss_spmm_minplus_local, scatter_min
+from repro.kernels.scatter_min import TILE
+from repro.kernels.ref import bvss_spmm_minplus_ref, scatter_min_ref
 
 #: the counted engine's device counters, in the order it returns them:
 #: inner relaxation ``rounds``, outer ``buckets``, and over the rounds
 #: ``pull_slots`` the queue slots pulled (``PULL_CHUNK`` × chunks),
-#: ``live_vss`` the sum of the live VSS count and ``pull_chunks``
+#: ``live_vss`` the sum of the live VSS count, ``pull_chunks``, and
+#: ``finite_rows`` the pulled slice rows with a finite candidate for some
+#: source (the pairs of the update that can lower a distance)
 SSSP_COUNTERS = ("rounds", "buckets", "pull_slots", "live_vss",
-                 "pull_chunks")
+                 "pull_chunks", "finite_rows")
 
 
 def default_delta(weights: np.ndarray) -> float:
@@ -123,7 +128,8 @@ def make_sssp(problem: BlestProblem, wplane: jnp.ndarray, n_sources: int, *,
         raise ConfigError(
             "sssp needs a weight plane: prepare(..., weights=...) or let "
             "GraphSession default to unit weights")
-    impl = None if use_kernel else bvss_spmm_minplus_ref
+    impl, scatter = ((None, scatter_min) if use_kernel
+                     else (bvss_spmm_minplus_ref, _xla_scatter))
     cap = max_rounds if max_rounds is not None else problem.n + 2
     if problem.mesh is not None:
         if problem.is_2d:
@@ -134,22 +140,39 @@ def make_sssp(problem: BlestProblem, wplane: jnp.ndarray, n_sources: int, *,
             raise ConfigError("sssp counters exist only on the "
                               "single-device engine")
         return _make_sssp_sharded(problem, wplane, n_sources, impl=impl,
-                                  cap=cap)
-    return _make_sssp_single(problem, wplane, n_sources, impl=impl, cap=cap,
-                             counters=counters)
+                                  scatter=scatter, cap=cap)
+    return _make_sssp_single(problem, wplane, n_sources, impl=impl,
+                             scatter=scatter, cap=cap, counters=counters)
+
+
+def _xla_scatter(dist, rows, vals, n_pairs):
+    """The jnp scatter-min of every pair (``use_kernel=False``): the pairs
+    past ``n_pairs`` are the dummy VSS's, all +inf, so it needs no
+    count."""
+    return scatter_min_ref(dist, rows, vals)
+
+
+def _whole_tiles(rows: int) -> int:
+    """``rows`` rounded up to whole (8, 128) float32 tiles: the engines'
+    distance width, so that the update kernel takes the distances as a
+    bitcast and never pads or slices a copy of them."""
+    return -(-rows // TILE) * TILE
 
 
 def _relaxation(dev, wplane: jnp.ndarray, p: BlestProblem, n_sources: int,
-                impl) -> Callable:
-    """``relax(dist, xg) -> (dist, (slots, live, chunks))``: one frontier
-    relaxation, the ONE body the single-device and the sharded engines
-    run.  ``xg`` (S, ≥ n_sets·σ) holds the frontier's distances over the
-    GLOBAL columns, +inf off the frontier; ``dist`` (S, rows) the rows
-    this device owns, scattered through ``row_ids`` (dummy rows past the
-    end are dropped or masked by the caller).  The queue capacity is the
-    VSS count rounded up to whole chunks, padded with the dummy VSS, so
-    no chunk's start is clamped."""
+                impl, scatter, count_finite: bool = False) -> Callable:
+    """``relax(dist, xg) -> (dist, (slots, live, chunks, finite))``: one
+    frontier relaxation, the ONE body the single-device and the sharded
+    engines run.  ``xg`` (S, ≥ n_sets·σ) holds the frontier's distances
+    over the GLOBAL columns, +inf off the frontier; ``dist`` (S, rows)
+    the rows this device owns, scattered through ``row_ids`` (dummy rows
+    past the end are dropped or masked by the caller) by ``scatter(dist,
+    rows, vals, live pairs)``; ``finite`` is counted only with
+    ``count_finite`` (else 0).  The queue capacity is the VSS count
+    rounded up to whole chunks, padded with the dummy VSS, so no chunk's
+    start is clamped."""
     sigma, n_sets, S = p.sigma, p.n_sets, n_sources
+    tau = (32 // sigma) * 32
     compact = make_compactor(dev, p.num_vss, chunk_ceil(max(p.num_vss, 1)))
 
     def relax(dist: jnp.ndarray, xg: jnp.ndarray):
@@ -158,34 +181,44 @@ def _relaxation(dev, wplane: jnp.ndarray, p: BlestProblem, n_sources: int,
                           < jnp.inf).any(axis=(0, 2))
             Q, count = compact(set_active)
 
-        def chunk(i, d):
+        def chunk(i, c):
+            d, finite = c
             ids = jax.lax.dynamic_slice_in_dim(Q, i * PULL_CHUNK, PULL_CHUNK)
             y = bvss_spmm_minplus_local(
                 dev.masks[ids], wplane[ids], dev.virtual_to_real[ids], xg,
                 sigma=sigma, impl=impl)                  # (S, C, τ)
             with jax.named_scope("sssp.update"):
                 rows = dev.row_ids[ids].reshape(-1)
-                return d.at[:, rows].min(y.reshape(S, -1))
+                y = y.reshape(S, -1)
+                live = jnp.clip(count - i * PULL_CHUNK, 0, PULL_CHUNK) * tau
+                # a finite candidate always has a real row: the dummy VSS
+                # and a VSS's padding slices have no edge
+                if count_finite:
+                    finite += jnp.sum((y < jnp.inf).any(axis=0),
+                                      dtype=jnp.int32)
+                return scatter(d, rows, y, live), finite
 
         n_chunks = chunk_ceil(count) // PULL_CHUNK
         with jax.named_scope("sssp.expand"):
-            dist = jax.lax.fori_loop(0, n_chunks, chunk, dist)
-        return dist, (n_chunks * PULL_CHUNK, count, n_chunks)
+            dist, finite = jax.lax.fori_loop(0, n_chunks, chunk,
+                                             (dist, jnp.int32(0)))
+        return dist, (n_chunks * PULL_CHUNK, count, n_chunks, finite)
 
     return relax
 
 
 def _make_sssp_single(p: BlestProblem, wplane: jnp.ndarray, n_sources: int,
-                      *, impl, cap: int, counters: bool) -> SSSPEngine:
+                      *, impl, scatter, cap: int,
+                      counters: bool) -> SSSPEngine:
     n, S = p.n, n_sources
-    ncols = p.n_sets * p.sigma
+    ncols = _whole_tiles(p.n_sets * p.sigma)
 
     @jax.named_scope("sssp.bucket")
     def sssp(arrays, sources, delta):
         masks, row_ids, v2r, wpl = arrays
         dev = p.dev._replace(masks=masks, row_ids=row_ids,
                              virtual_to_real=v2r)
-        relax = _relaxation(dev, wpl, p, S, impl)
+        relax = _relaxation(dev, wpl, p, S, impl, scatter, counters)
         valid = jnp.arange(ncols) < n                    # padding columns
         delta = delta.astype(jnp.float32)
         each = jnp.arange(S)                             # one row a source
@@ -200,7 +233,7 @@ def _make_sssp_single(p: BlestProblem, wplane: jnp.ndarray, n_sources: int,
 
             def inner(c):
                 dist, fr, it, tally = c
-                d2, (slots, live, chunks) = relax(
+                d2, (slots, live, chunks, finite) = relax(
                     dist, jnp.where(fr, dist, jnp.inf))
                 # dummy-row scatters may land in padding columns (row n <
                 # ncols): wipe them so the padding never re-enters a
@@ -208,7 +241,7 @@ def _make_sssp_single(p: BlestProblem, wplane: jnp.ndarray, n_sources: int,
                 d2 = jnp.where(valid, d2, jnp.inf)
                 fr2 = (d2 < dist) & (d2 < btop[:, None])
                 return d2, fr2, it + 1, tally + jnp.stack(
-                    [1, 0, slots, live, chunks])
+                    [1, 0, slots, live, chunks, finite])
 
             dist, fr, _, tally = jax.lax.while_loop(
                 lambda c: c[1].any() & (c[2] < cap),
@@ -232,7 +265,7 @@ def _make_sssp_single(p: BlestProblem, wplane: jnp.ndarray, n_sources: int,
 
 
 def _make_sssp_sharded(p: BlestProblem, wplane: jnp.ndarray, n_sources: int,
-                       *, impl, cap: int) -> SSSPEngine:
+                       *, impl, scatter, cap: int) -> SSSPEngine:
     from jax.sharding import PartitionSpec as P
 
     from repro.core.bvss import ShardedBVSSDevice
@@ -241,14 +274,15 @@ def _make_sssp_sharded(p: BlestProblem, wplane: jnp.ndarray, n_sources: int,
     mesh, axis = p.mesh, p.axis
     n = p.n
     rps = p.rows_per_shard
+    width = _whole_tiles(rps + 1)                        # + the dummy row
     S = n_sources
 
     def local_loop(masks, row_ids, v2r, wpl, sources, delta):
         dev = ShardedBVSSDevice(masks[0], row_ids[0], v2r[0], None, None)
-        relax = _relaxation(dev, wpl[0], p, S, impl)
+        relax = _relaxation(dev, wpl[0], p, S, impl, scatter)
         d = jax.lax.axis_index(axis)
         lvalid = (d * rps + jnp.arange(rps)) < n         # real local rows
-        rowmask = jnp.concatenate([lvalid, jnp.zeros((1,), bool)])
+        rowmask = jnp.concatenate([lvalid, jnp.zeros((width - rps,), bool)])
         delta32 = delta.astype(jnp.float32)
 
         def relax_local(dist: jnp.ndarray, fr: jnp.ndarray):
@@ -265,7 +299,7 @@ def _make_sssp_sharded(p: BlestProblem, wplane: jnp.ndarray, n_sources: int,
         each = jnp.arange(S)                             # one row a source
         lsrc = sources - d * rps
         own = (lsrc >= 0) & (lsrc < rps)
-        dist = jnp.full((S, rps + 1), jnp.inf, jnp.float32)
+        dist = jnp.full((S, width), jnp.inf, jnp.float32)
         dist = dist.at[each, jnp.where(own, lsrc, rps)].set(
             jnp.where(own, 0.0, jnp.inf))
         fr = jnp.zeros((S, rps), bool).at[

@@ -15,7 +15,10 @@ exported here compiles for TPU v5e at deployment widths
   plane;
 * ``bvss_spmm*``: ``(8, 32)`` mask blocks (compiled), slice tiles built
   from shifts and masks, batched MXU contractions (int8, or float32 at
-  ``Precision.HIGHEST``), an unrolled elementwise min for min-plus.
+  ``Precision.HIGHEST``), an unrolled elementwise min for min-plus;
+* ``scatter_min``: the distance rows held in VMEM as (8, 128) tiles in
+  four banks, the (row, value) pairs streamed through SMEM and walked
+  with scalar control, one branch-free read-min-write a pair.
 
 :func:`resolve_interpret` is the ONE place that decides interpret-vs-
 compiled for every Pallas entry point (DESIGN §2.8) — the per-kernel
@@ -59,6 +62,7 @@ from .mxu_pull import (bit_spmm, bvss_spmm, bvss_spmm_minplus,  # noqa: E402
                        bvss_spmm_t_local, bvss_spmm_w, bvss_spmm_w_local)
 from .frontier_finalize import (finalize_pack_sweep,          # noqa: E402
                                 finalize_sweep)
+from .scatter_min import scatter_min, scatter_min_kernel      # noqa: E402
 from . import ref                                             # noqa: E402
 
 
@@ -78,4 +82,5 @@ __all__ = ["resolve_interpret", "bvss_pull", "bvss_push", "bit_spmm",
            "bvss_spmm", "bvss_spmm_minplus", "bvss_spmm_minplus_local",
            "bvss_spmm_t", "bvss_spmm_t_local", "bvss_spmm_w",
            "bvss_spmm_w_local", "finalize_sweep", "finalize_pack_sweep",
-           "pull_vss_kernel", "push_vss_kernel", "ref"]
+           "pull_vss_kernel", "push_vss_kernel", "scatter_min",
+           "scatter_min_kernel", "ref"]

@@ -86,6 +86,16 @@ def bvss_spmm_minplus_ref(masks: jnp.ndarray, wvals: jnp.ndarray,
     return jnp.min(w[None] + x, axis=2)
 
 
+def scatter_min_ref(dist: jnp.ndarray, rows: jnp.ndarray, vals: jnp.ndarray,
+                    n_pairs=None) -> jnp.ndarray:
+    """Oracle for kernels.scatter_min: ``dist.at[:, rows].min(vals)``,
+    rows at or past the end dropped; with ``n_pairs``, the pairs past it
+    carry +inf (they change nothing)."""
+    if n_pairs is not None:
+        vals = jnp.where(jnp.arange(rows.shape[0]) < n_pairs, vals, jnp.inf)
+    return dist.at[:, rows].min(vals)
+
+
 def bvss_spmm_t_ref(masks: jnp.ndarray, hvals: jnp.ndarray, sigma: int = 8
                     ) -> jnp.ndarray:
     """Oracle for kernels.bvss_spmm_t: (B, σ, S) float32 transposed

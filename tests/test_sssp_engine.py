@@ -1,9 +1,10 @@
 """The delta-stepping SSSP engine (DESIGN §2.9) against SciPy's Dijkstra
 (``kernels/ref.sssp_ref``) on seeded random weights: its relaxation
 pulls the live queue in chunks of ``PULL_CHUNK`` slots at every live VSS
-count around the chunk edges, its counters account the chunks, the
-manager's ``sssp`` verb answers in caller ids under one traced query,
-and every operation of its loops maps to a named stage."""
+count around the chunk edges, its counters account the chunks and the
+finite pairs, its update kernel gives bitwise the distances of the jnp
+scatter, the manager's ``sssp`` verb answers in caller ids under one
+traced query, and every operation of its loops maps to a named stage."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,7 +19,8 @@ from repro.core.bvss import build_bvss, build_weight_plane, \
 from repro.errors import GraphValidationError
 from repro.graphs import from_edges, generators as gen, src_of_edges
 from repro.kernels.ref import sssp_ref
-from repro.serve import GraphSessionManager
+from repro.serve import GraphSession, GraphSessionManager
+from conftest import require_devices
 from test_pull_chunks import LIVE, fan, live_counts
 
 C = PULL_CHUNK
@@ -53,16 +55,37 @@ def case(request):
     return request.param, g, b, w, counts
 
 
-def test_relaxation_chunks_match_the_reference(case):
+def _bits(x) -> np.ndarray:
+    return np.asarray(x).view(np.uint32)
+
+
+def test_relaxation_chunks_match_the_reference(case, monkeypatch):
     """Δ above every distance: one bucket whose rounds are the BFS levels
-    of the fan, so round r pulls exactly the live VSSs of level r - 1."""
+    of the fan, so round r pulls exactly the live VSSs of level r - 1.
+    ``finite_rows`` is the dense count of the finite pairs with a real
+    row that the jnp scatter saw; the update kernel, over the live pairs
+    alone, gives bitwise the same distances and counters."""
     name, g, b, w, counts = case
     p = BlestProblem.build(b)
-    fn = make_sssp(p, weight_plane_to_device(build_weight_plane(g, w)), 1,
-                   use_kernel=False, counters=True)
+    wplane = weight_plane_to_device(build_weight_plane(g, w))
+    seen = []
+
+    def counted_scatter(dist, rows, vals, n_pairs):
+        jax.debug.callback(lambda r, v: seen.append(
+            int((np.isfinite(v).any(axis=0) & (r < g.n)).sum())), rows, vals)
+        return dist.at[:, rows].min(vals)
+
+    monkeypatch.setattr(sssp_mod, "_xla_scatter", counted_scatter)
+    fn = make_sssp(p, wplane, 1, use_kernel=False, counters=True)
     dist, c = fn(jnp.asarray([0], jnp.int32), jnp.float32(4.0))
     assert_close(np.asarray(dist)[0], sssp_ref(g, [0], w)[0])
-    rounds, buckets, slots, live, chunks = (int(x) for x in c)
+    kdist, kc = make_sssp(p, wplane, 1, counters=True)(
+        jnp.asarray([0], jnp.int32), jnp.float32(4.0))
+    np.testing.assert_array_equal(_bits(kdist), _bits(dist))
+    assert list(np.asarray(kc)) == list(np.asarray(c))
+    rounds, buckets, slots, live, chunks, finite = (int(x) for x in c)
+    assert len(seen) == chunks and finite == sum(seen)
+    assert (finite > 0) == (live > 0) and finite <= 128 * live
     assert buckets == 1
     assert rounds == len(counts) + 1      # the last frontier pulls nothing
     assert live == sum(counts)
@@ -81,6 +104,26 @@ def _kron():
     g = from_edges(g.n, np.concatenate([src, dst]),
                    np.concatenate([dst, src]), dedup=True)
     return g, kernel3_weights(g, np.random.default_rng(11))
+
+
+@pytest.mark.parametrize("mesh", ["single", "sharded"])
+def test_update_kernel_is_bitwise_the_jnp_scatter(mesh):
+    """On the Kernel 3 Kronecker graph, a cohort of three sources: the
+    engine with its kernels gives bitwise the distances of the engine
+    with the jnp forms, on one device and on each shard of a 1-D mesh."""
+    g, w = _kron()
+    deg = np.diff(g.indptr)
+    srcs = [3, int(np.argmax(deg)), int(np.flatnonzero(deg)[-1])]
+    kw = {}
+    if mesh == "sharded":
+        require_devices(2)
+        from repro.distributed.bfs_dist import bfs_mesh
+        kw = {"mesh": bfs_mesh(2)}
+    got = [GraphSession(g, weights=w, max_batch=3, use_kernel=k,
+                        **kw).sssp_batch(srcs) for k in (True, False)]
+    np.testing.assert_array_equal(got[0].view(np.uint64),
+                                  got[1].view(np.uint64))
+    assert_close(got[0], sssp_ref(g, srcs, w))
 
 
 def test_manager_sssp_caller_ids_one_query(monkeypatch):
@@ -130,6 +173,10 @@ def test_every_sssp_loop_operation_has_a_stage():
     assert ops and all(stages.get(i.name) in tracing.SSSP_STAGES
                        for i in ops)
     assert set(stages.values()) == set(tracing.SSSP_STAGES)
+    # the update kernel (interpreted here) lies in sssp.update alone, so
+    # that the min-plus roofline's sssp.expand gains nothing of it
+    kernel = [i for i in ops if "scatter_min" in i.op_name]
+    assert kernel and {stages[i.name] for i in kernel} == {"sssp.update"}
     dist, _ = fn(jnp.asarray([3], jnp.int32), jnp.float32(0.5))
     assert_close(np.asarray(jax.device_get(dist))[0],
                  sssp_ref(g, [3], w)[0])

@@ -4,7 +4,8 @@ Interpret mode (the rest of the suite) never checks Mosaic's tiling rules
 or VMEM limits, so each entry point is compiled here, ahead of time, for
 one chip of a described ``v5e:2x2`` topology at deployment widths: 4096
 queued VSSs (and the single-source pull's chunk), 64 stacked sources,
-σ = 8, 2^20 vertices.  Nothing runs;
+σ = 8, 2^20 vertices (the SSSP update's scatter-min at the Kernel 3
+cell's (1, 2^19) distances and 512 × 128 pairs).  Nothing runs;
 each test asserts the compiled program holds the Mosaic kernel
 (``tpu_custom_call``).
 
@@ -86,6 +87,16 @@ CASES = {
             m, rows, h, sigma=SIGMA,
             impl=lambda *a, **k: K.bvss_spmm_t(*a, **k, interpret=False)),
         [((B, 32), U32), ((B, SPW, 32), I32), ((N + 1, S), F32)]),
+    "scatter_min[cell]": (
+        lambda d, rows, y, n: K.scatter_min_kernel(d, rows, y, n,
+                                                   interpret=False),
+        [((1, 1 << 19), F32), ((PULL_CHUNK * 128,), I32),
+         ((1, PULL_CHUNK * 128), F32), ((), I32)]),
+    "scatter_min[S=8]": (
+        lambda d, rows, y, n: K.scatter_min_kernel(d, rows, y, n,
+                                                   interpret=False),
+        [((8, 3 * (1 << 16) + 1), F32), ((PULL_CHUNK * 128,), I32),
+         ((8, PULL_CHUNK * 128), F32), ((), I32)]),
     "bvss_spmm_minplus_local": (
         lambda m, w, sets, xg: K.bvss_spmm_minplus_local(
             m, w, sets, xg, sigma=SIGMA,
@@ -192,6 +203,11 @@ def test_sssp_engine_compiles_lane_dense_for_v5e(one_chip, monkeypatch):
            if i.opcode in ("fusion", "custom-call")]
     assert ops and all(stages.get(i.name) in tracing.SSSP_STAGES
                        for i in ops)
+    kernels = {(i.name.split(".")[0], stages[i.name]) for i in ops
+               if i.opcode == "custom-call"
+               and i.name.startswith(("bvss_", "sssp_"))}
+    assert kernels == {("bvss_spmm_minplus", "sssp.expand"),
+                       ("sssp_scatter_min", "sssp.update")}
     floats = re.findall(r"f32\[([\d,]+)\]\{([\d,]+)", text)
     assert any(d.split(",")[-1] == "128" for d, _ in floats)
     for dims, layout in floats:
