@@ -55,7 +55,7 @@ import numpy as np
 
 from repro.analytics.common import pad_cohort
 from repro.core.bfs import (BlestProblem, QueueHistory, make_queue_history,
-                            queue_widths)
+                            queue_capacity)
 from repro.core.bvss import ShardedBVSSDevice
 from repro.core.level_pipeline import LevelPipeline, run_levels_recorded
 from repro.core.multi_source import (INF, _make_ms_locals,
@@ -66,7 +66,7 @@ from repro.kernels.ref import bvss_spmm_ref, bvss_spmm_t_ref, bvss_spmm_w_ref
 
 
 def make_betweenness(problem: BlestProblem, n_sources: int, *,
-                     use_kernel: bool = True, buckets: int = 2,
+                     use_kernel: bool = True,
                      max_levels: int | None = None,
                      spmm_w_impl: Callable | None = None) -> Callable:
     """Build jitted ``f(sources (S,) i32) -> (levels (n,S), sigma (n,S),
@@ -86,22 +86,19 @@ def make_betweenness(problem: BlestProblem, n_sources: int, *,
         if p.is_2d:
             return _make_betweenness_sharded_2d(p, n_sources,
                                                 use_kernel=use_kernel,
-                                                buckets=buckets,
                                                 max_levels=max_levels,
                                                 spmm_w_impl=spmm_w_impl)
         return _make_betweenness_sharded(p, n_sources,
                                          use_kernel=use_kernel,
-                                         buckets=buckets,
                                          max_levels=max_levels,
                                          spmm_w_impl=spmm_w_impl)
     S = n_sources
     n, sigma = p.n, p.sigma
     dev = p.dev
-    eng = make_ms_engine(p, S, use_kernel=use_kernel, buckets=buckets,
-                         track_sigma=True, spmm_w_impl=spmm_w_impl)
+    eng = make_ms_engine(p, S, use_kernel=use_kernel, track_sigma=True,
+                         spmm_w_impl=spmm_w_impl)
     spmm_t = bvss_spmm_t if use_kernel else bvss_spmm_t_ref
-    widths = queue_widths(p.num_vss, buckets)
-    qcap = widths[-1]
+    qcap = queue_capacity(p.num_vss)
     max_lv = max_levels if max_levels is not None else n + 1
     n_cols = p.n_sets * sigma
     hist0, record = make_queue_history(qcap, max_lv, p.num_vss)
@@ -156,7 +153,7 @@ def make_betweenness(problem: BlestProblem, n_sources: int, *,
 
 
 def _make_betweenness_sharded(p: BlestProblem, n_sources: int, *,
-                              use_kernel: bool, buckets: int,
+                              use_kernel: bool,
                               max_levels: int | None,
                               spmm_w_impl: Callable | None = None
                               ) -> Callable:
@@ -187,10 +184,9 @@ def _make_betweenness_sharded(p: BlestProblem, n_sources: int, *,
     spmm_w = spmm_w_impl if spmm_w_impl is not None else \
         (bvss_spmm_w if use_kernel else bvss_spmm_w_ref)
     spmm_t = bvss_spmm_t if use_kernel else bvss_spmm_t_ref
-    widths = queue_widths(p.num_vss, buckets)
-    qcap = widths[-1]
+    qcap = queue_capacity(p.num_vss)
     max_lv = max_levels if max_levels is not None else p.n + 1
-    locals_for = _make_ms_locals(p, S, spmm, widths, qcap, spmm_w=spmm_w,
+    locals_for = _make_ms_locals(p, S, spmm, qcap, spmm_w=spmm_w,
                                  track_sigma=True)
     hist0, record = make_queue_history(qcap, max_lv, p.num_vss)
 
@@ -269,7 +265,7 @@ def _make_betweenness_sharded(p: BlestProblem, n_sources: int, *,
 
 
 def _make_betweenness_sharded_2d(p: BlestProblem, n_sources: int, *,
-                                 use_kernel: bool, buckets: int,
+                                 use_kernel: bool,
                                  max_levels: int | None,
                                  spmm_w_impl: Callable | None = None
                                  ) -> Callable:
@@ -307,11 +303,10 @@ def _make_betweenness_sharded_2d(p: BlestProblem, n_sources: int, *,
     spmm_w = spmm_w_impl if spmm_w_impl is not None else \
         (bvss_spmm_w if use_kernel else bvss_spmm_w_ref)
     spmm_t = bvss_spmm_t if use_kernel else bvss_spmm_t_ref
-    widths = queue_widths(p.num_vss, buckets)
-    qcap = widths[-1]
+    qcap = queue_capacity(p.num_vss)
     max_lv = max_levels if max_levels is not None else p.n + 1
-    locals_for = _make_ms_locals_2d(p, S, spmm, widths, qcap,
-                                    spmm_w=spmm_w, track_sigma=True)
+    locals_for = _make_ms_locals_2d(p, S, spmm, qcap, spmm_w=spmm_w,
+                                    track_sigma=True)
     hist0, record = make_queue_history(qcap, max_lv, p.num_vss)
 
     def local_fn(masks: jnp.ndarray, row_ids: jnp.ndarray,

@@ -13,7 +13,7 @@ driver is a bucketed label-correcting loop — batched delta-stepping:
   (vertices whose distance improved and sits below ``btop``) is compacted
   set-wise through the SAME ``make_compactor`` queue the BFS engines use,
   then pulled in chunks of ``PULL_CHUNK`` queue slots, as many as the
-  live VSS count needs (the single-source BFS pull's loop, DESIGN §2.3):
+  live VSS count needs (every engine's pull loop, DESIGN §2.3):
   each chunk gathers its masks, weight-plane rows and σ-column distances,
   runs the min-plus tile product and scatter-``min``s into the distance
   rows (the Pallas ``scatter_min``, which walks the chunk's live pairs
@@ -54,8 +54,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analytics.common import pad_cohort
-from repro.core.bfs import (PULL_CHUNK, BlestProblem, chunk_ceil,
-                            make_compactor)
+from repro.core.bfs import (PULL_CHUNK, BlestProblem, chunk_count,
+                            make_compactor, pull_chunks, queue_capacity)
 from repro.errors import ConfigError
 from repro.kernels import bvss_spmm_minplus_local, scatter_min
 from repro.kernels.scatter_min import TILE
@@ -168,12 +168,10 @@ def _relaxation(dev, wplane: jnp.ndarray, p: BlestProblem, n_sources: int,
     the rows this device owns, scattered through ``row_ids`` (dummy rows
     past the end are dropped or masked by the caller) by ``scatter(dist,
     rows, vals, live pairs)``; ``finite`` is counted only with
-    ``count_finite`` (else 0).  The queue capacity is the VSS count
-    rounded up to whole chunks, padded with the dummy VSS, so no chunk's
-    start is clamped."""
+    ``count_finite`` (else 0)."""
     sigma, n_sets, S = p.sigma, p.n_sets, n_sources
     tau = (32 // sigma) * 32
-    compact = make_compactor(dev, p.num_vss, chunk_ceil(max(p.num_vss, 1)))
+    compact = make_compactor(dev, p.num_vss, queue_capacity(p.num_vss))
 
     def relax(dist: jnp.ndarray, xg: jnp.ndarray):
         with jax.named_scope("sssp.bucket"):
@@ -181,9 +179,8 @@ def _relaxation(dev, wplane: jnp.ndarray, p: BlestProblem, n_sources: int,
                           < jnp.inf).any(axis=(0, 2))
             Q, count = compact(set_active)
 
-        def chunk(i, c):
+        def chunk(i, ids, c):
             d, finite = c
-            ids = jax.lax.dynamic_slice_in_dim(Q, i * PULL_CHUNK, PULL_CHUNK)
             y = bvss_spmm_minplus_local(
                 dev.masks[ids], wplane[ids], dev.virtual_to_real[ids], xg,
                 sigma=sigma, impl=impl)                  # (S, C, τ)
@@ -198,10 +195,10 @@ def _relaxation(dev, wplane: jnp.ndarray, p: BlestProblem, n_sources: int,
                                       dtype=jnp.int32)
                 return scatter(d, rows, y, live), finite
 
-        n_chunks = chunk_ceil(count) // PULL_CHUNK
+        n_chunks = chunk_count(count)
         with jax.named_scope("sssp.expand"):
-            dist, finite = jax.lax.fori_loop(0, n_chunks, chunk,
-                                             (dist, jnp.int32(0)))
+            dist, finite = pull_chunks(Q, n_chunks, chunk,
+                                       (dist, jnp.int32(0)))
         return dist, (n_chunks * PULL_CHUNK, count, n_chunks, finite)
 
     return relax

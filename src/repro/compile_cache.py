@@ -1,9 +1,9 @@
 """JAX's persistent compilation cache for the repo's entry points.
 
 Every program entry point (``chip_smoke.py``, the ``repro.launch.bfs`` and
-``repro.launch.analytics`` launchers, ``benchmarks/run.py``) calls
-:func:`use_compile_cache` before its first compile, so repeat runs on the
-same machine skip recompiling the engines.
+``repro.launch.analytics`` launchers, the chip benchmark ``bench/run.py``)
+calls :func:`use_compile_cache` before its first compile, so repeat runs
+on the same machine skip recompiling the engines.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself; the
   directory is left to it and no other is set in code.

@@ -61,8 +61,8 @@ from repro.kernels.ref import bvss_push_ref, finalize_pack_ref
 
 INF = np.int32(np.iinfo(np.int32).max)
 
-PULL_TILE = 128  # queue widths are padded to this (bvss_pull tile size)
-#: queue slots one chunk of the single-source pull gathers, pulls and
+PULL_TILE = 128  # push vertex queues are padded to this (bvss_pull tile)
+#: queue slots one chunk of every engine's pull gathers, pulls and
 #: scatters; a pull level runs ceil(live VSSs / PULL_CHUNK) chunks
 PULL_CHUNK = 4 * PULL_TILE
 
@@ -272,6 +272,17 @@ def chunk_ceil(x):
     return (x + PULL_CHUNK - 1) // PULL_CHUNK * PULL_CHUNK
 
 
+def queue_capacity(num_vss: int) -> int:
+    """The compacted VSS queue's static length: every VSS, rounded up to
+    whole chunks (dummy-padded), so no chunk's start is clamped."""
+    return chunk_ceil(max(num_vss, 1))
+
+
+def chunk_count(count):
+    """The chunks a pull over ``count`` live VSSs runs (a traced int)."""
+    return chunk_ceil(count) // PULL_CHUNK
+
+
 def _tally(tally: jnp.ndarray | None, use_push, count
            ) -> jnp.ndarray | None:
     """Add one level to the counters after ``levels`` (the loop counts its
@@ -279,7 +290,7 @@ def _tally(tally: jnp.ndarray | None, use_push, count
     if tally is None:
         return None
     pull = 1 - jnp.asarray(use_push, dtype=jnp.int32)
-    chunks = pull * (chunk_ceil(count) // PULL_CHUNK)
+    chunks = pull * chunk_count(count)
     return tally + jnp.stack([1 - pull, chunks * PULL_CHUNK, pull * count,
                               chunks])
 
@@ -288,53 +299,24 @@ def _round_width(x: int) -> int:
     return max(PULL_TILE, ((x + PULL_TILE - 1) // PULL_TILE) * PULL_TILE)
 
 
-def queue_widths(num_vss: int, buckets: int) -> list[int]:
-    """Static queue widths, smallest first; the on-device live VSS count
-    picks one via a cond chain (DESIGN §2.3/§2.8).  The multi-source and
-    2-D engines run this ladder; the single-source engines pull in
-    ``PULL_CHUNK``s and take only its last width, as the queue capacity.
+def pull_chunks(Q: jnp.ndarray, n_chunks, body: Callable, init):
+    """The one pull loop of every engine (DESIGN §2.3): ``body(i, ids,
+    carry)`` over chunks ``i < n_chunks`` of the compacted queue ``Q``,
+    chunk ``i`` holding the ``PULL_CHUNK`` VSS ids from slot ``i ·
+    PULL_CHUNK`` (dummy VSSs past the live count).  ``n_chunks`` is
+    ``chunk_count(count)``; the caller makes it, under its own named
+    scope, and may count it.
+    A pull reads only the frontier, which no chunk changes, and each
+    engine's write splits exactly over chunks (the min of a level, the
+    max of a mark, a sum of σ partials), so the chunks give the one-shot
+    answer.  ``len(Q)`` must be a multiple of ``PULL_CHUNK``
+    (:func:`queue_capacity`).  Under ``shard_map`` each shard runs its
+    own trip count: ``body`` must hold no collective."""
+    def chunk(i, carry):
+        ids = jax.lax.dynamic_slice_in_dim(Q, i * PULL_CHUNK, PULL_CHUNK)
+        return body(i, ids, carry)
 
-    The ladder is geometric with ratio 8: ``buckets`` graduations
-    ``num_vss / 8^(buckets-1), ..., num_vss / 8, num_vss`` rounded up to
-    the PULL_TILE floor, deduplicated ascending (the full width is always
-    last).  ``buckets=1`` is the always-full-queue degenerate case;
-    ``buckets=2`` reproduces the original small/full pair.  A bucket count
-    < 1 is a :class:`repro.errors.ConfigError` — never a silent fallback.
-    """
-    if buckets < 1:
-        raise ConfigError(
-            f"queue_widths needs buckets >= 1, got {buckets!r}")
-    widths: list[int] = []
-    for i in range(buckets - 1, -1, -1):
-        w = _round_width(-(-num_vss // 8 ** i))
-        if not widths or w > widths[-1]:
-            widths.append(w)
-    return widths
-
-
-def select_width(widths: list[int], count, apply: Callable):
-    """Run ``apply(width)`` for the smallest ladder width holding ``count``
-    live entries (full width fallback) via a nested ``lax.cond`` chain —
-    the XLA stand-in for a dynamically-sized launch."""
-    if len(widths) == 1:
-        return apply(widths[0])
-
-    def chain(i: int):
-        if i == len(widths) - 1:
-            return lambda: apply(widths[i])
-        return lambda: jax.lax.cond(count <= widths[i],
-                                    lambda: apply(widths[i]), chain(i + 1))
-
-    return chain(0)()
-
-
-def selected_width(widths: list[int], count) -> jnp.ndarray:
-    """The scalar width :func:`select_width` would pick — the pull-side
-    term of the direction heuristic's work model."""
-    pw = jnp.int32(widths[-1])
-    for w in reversed(widths[:-1]):
-        pw = jnp.where(count <= w, jnp.int32(w), pw)
-    return pw
+    return jax.lax.fori_loop(0, n_chunks, chunk, init)
 
 
 def make_vertex_compactor(n_fwords: int, dummy_vertex: int, pqcap: int
@@ -430,13 +412,10 @@ def _make_hybrid_step(dev, pull: PullFn, push: PushFn | None, sigma: int,
     """The direction-optimizing level step (DESIGN §2.3/§2.8) — the ONE
     step body both the single-device and the shard_map'd engines run.
 
-    Pull side: a loop over the compacted VSS queue in chunks of
-    ``PULL_CHUNK`` slots, ``ceil(count / PULL_CHUNK)`` of them: each
-    gathers its VSSs' masks and frontier bytes, pulls, and scatters.  The
-    pull reads only the frontier, which no chunk changes, and the scatter
-    (min of the level, max of the marks) is idempotent, so the chunks
-    give the one-shot answer exactly.  ``qcap`` (the queue length) must
-    be a multiple of ``PULL_CHUNK``, so no chunk's start is clamped.
+    Pull side: :func:`pull_chunks` over the compacted VSS queue: each
+    chunk gathers its VSSs' masks and frontier bytes, pulls, and scatters
+    (min of the level, max of the marks).  ``qcap`` is the queue length
+    (:func:`queue_capacity`).
     Push side: compact the frontier BITS into a vertex queue, expand each
     vertex into the ≤ R VSSs of its own slice set, and resolve each
     (vertex, VSS) pair with the one-hot push kernel — processing width
@@ -485,9 +464,7 @@ def _make_hybrid_step(dev, pull: PullFn, push: PushFn | None, sigma: int,
                                            state.count))
 
     def pull_step(state: _BlestState, lvl) -> _BlestState:
-        def chunk(i, out):
-            ids = jax.lax.dynamic_slice_in_dim(state.Q, i * PULL_CHUNK,
-                                               PULL_CHUNK)
+        def chunk(i, ids, out):
             fbytes = _frontier_bytes(state.F, dev.virtual_to_real[ids],
                                      sigma)
             hits = pull(dev.masks[ids], fbytes, sigma)  # (C, spw, 32)
@@ -495,8 +472,8 @@ def _make_hybrid_step(dev, pull: PullFn, push: PushFn | None, sigma: int,
                            hits.reshape(-1), lvl)
 
         with jax.named_scope("bfs.expand"):
-            out = jax.lax.fori_loop(0, chunk_ceil(state.count) // PULL_CHUNK,
-                                    chunk, start(state))
+            out = pull_chunks(state.Q, chunk_count(state.count), chunk,
+                              start(state))
         return finish(state, out, False)
 
     if direction == "pull":
@@ -549,8 +526,7 @@ def resolve_push_cap(direction: str, push_cap: int | None, n: int) -> int:
 def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
                    pull_impl: PullFn | None = None,
                    push_impl: PushFn | None = None,
-                   use_kernels: bool = True, buckets: int = 2,
-                   widths: list[int] | None = None,
+                   use_kernels: bool = True,
                    direction: str = "auto", push_cap: int | None = None,
                    alpha: float = 4.0, max_levels: int | None = None,
                    counters: bool = False) -> Callable:
@@ -558,14 +534,14 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
 
     The level step is direction-optimizing (DESIGN §2.8): the pull side
     loops over the compacted queue in chunks of ``PULL_CHUNK`` slots, as
-    many as the live VSS count needs; the push side compacts the frontier
-    bits into a vertex queue and expands each vertex's own slice-set VSSs
-    through the one-hot push kernel.  Either way the scatter (min for
-    eager levels, max for lazy marks) and one fused finalise +
-    frontier-pack + set-flag sweep feed cumsum compaction.  A
-    mesh-sharded ``problem`` runs the same pipeline under ``shard_map``
-    (local pull/push/scatter/finalise, frontier all-gather, psum
-    convergence).
+    many as the live VSS count needs (:func:`pull_chunks`); the push side
+    compacts the frontier bits into a vertex queue and expands each
+    vertex's own slice-set VSSs through the one-hot push kernel.  Either
+    way the scatter (min for eager levels, max for lazy marks) and one
+    fused finalise + frontier-pack + set-flag sweep feed cumsum
+    compaction.  A mesh-sharded ``problem`` runs the same pipeline under
+    ``shard_map`` (local pull/push/scatter/finalise, frontier all-gather,
+    psum convergence).
 
     pull_impl:   custom pull (masks, fbytes, sigma) -> hits; overrides the
                  kernel/jnp switch.
@@ -574,13 +550,6 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
     use_kernels: route pull/push through the Pallas kernels and the tail
                  through Pallas ``finalize_pack_sweep`` (interpret-mode on
                  CPU); False = pure-jnp fallback for all three.
-    buckets:     graduations of the pull-width ladder (see
-                 :func:`queue_widths`); >= 1, ConfigError otherwise.
-    widths:      explicit pull-width ladder (ascending; overrides
-                 ``buckets`` — the autotuner's injection point).  Only
-                 the 2-D engine runs the ladder; the single-device and
-                 1-D engines pull in chunks and take ``widths[-1]``,
-                 rounded up to whole chunks, as the queue capacity.
     direction:   "auto" (per-level on-device switch), "pull", "push".
     push_cap:    auto-mode frontier cap (None = DEFAULT_PUSH_CAP; forced
                  push always uses the full vertex count).
@@ -601,9 +570,7 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
     if direction not in DIRECTIONS:
         raise ConfigError(
             f"direction must be one of {DIRECTIONS}, got {direction!r}")
-    if widths is None:
-        widths = queue_widths(p.num_vss, buckets)
-    qcap = chunk_ceil(widths[-1])
+    qcap = queue_capacity(p.num_vss)
     max_lv = max_levels if max_levels is not None else p.n + 1
     cap = resolve_push_cap(direction, push_cap, p.n)
 
@@ -626,7 +593,7 @@ def make_blest_bfs(problem: BlestProblem, *, lazy: bool,
             raise ConfigError("level counters exist only on the "
                               "single-device engine")
         if p.is_2d:
-            return _make_blest_bfs_sharded_2d(p, pull=pull, widths=widths,
+            return _make_blest_bfs_sharded_2d(p, pull=pull, qcap=qcap,
                                               max_lv=max_lv,
                                               direction=direction)
         return _make_blest_bfs_sharded(p, lazy=lazy, pull=pull, push=push,
@@ -784,13 +751,14 @@ def _make_blest_bfs_sharded(p: BlestProblem, *, lazy: bool, pull: PullFn,
 
 
 def _make_blest_bfs_sharded_2d(p: BlestProblem, *, pull: PullFn,
-                               widths: list[int], max_lv: int,
+                               qcap: int, max_lv: int,
                                direction: str) -> Callable:
     """The 2-D (row × column) mesh-native BLEST engine (DESIGN §2.4).
 
     Device (i, j) pulls its ROW block of vertices from its COLUMN block of
-    frontier words, so per level it runs the same bucketed pull as the 1-D
-    engine over ``1/cols`` of the frontier, then two butterfly collectives
+    frontier words, so per level it runs the same chunked pull as the 1-D
+    engine over ``1/cols`` of the frontier (each device over its own live
+    count, no collective inside the chunk loop), then two butterfly collectives
     replace the flat all-gather: an OR-allreduce of the packed partial hit
     words over the COLUMN axis (every column block saw a different frontier
     slice, so hits are partial), and a segment all-gather of the fresh
@@ -820,7 +788,6 @@ def _make_blest_bfs_sharded_2d(p: BlestProblem, *, pull: PullFn,
             "1-D partition holds — use a 1-D mesh or direction='pull'")
     mesh, rax, cax = p.mesh, p.axis, p.col_axis
     sigma = p.sigma
-    qcap = widths[-1]
     rps = p.rows_per_shard
     lwords = rps // 32
     cpb = p.cols_per_block
@@ -837,16 +804,15 @@ def _make_blest_bfs_sharded_2d(p: BlestProblem, *, pull: PullFn,
         j = jax.lax.axis_index(cax)
 
         def step(state: _BlestState, lvl) -> _BlestState:
-            def pull_marks(width: int):
-                ids = jax.lax.slice_in_dim(state.Q, 0, width)
+            def chunk(i, ids, marks):
                 fbytes = _frontier_bytes(state.F,
                                          dev.virtual_to_real[ids], sigma)
                 hits = pull(dev.masks[ids], fbytes, sigma)
-                marks = jnp.zeros((rps + 1,), dtype=jnp.uint8)
                 return marks.at[dev.row_ids[ids].reshape(-1)].max(
                     hits.reshape(-1).astype(jnp.uint8))
-            return state._replace(marks=select_width(widths, state.count,
-                                                     pull_marks))
+            marks = pull_chunks(state.Q, chunk_count(state.count), chunk,
+                                jnp.zeros((rps + 1,), dtype=jnp.uint8))
+            return state._replace(marks=marks)
 
         def finalize(state: _BlestState, lvl) -> _BlestState:
             # partial hits -> full row-block hits (butterfly OR over cols)
@@ -1136,12 +1102,10 @@ def make_engine(g: Graph, engine: str, *, sigma: int = 8,
                 problem: BlestProblem | None = None,
                 pull_impl: PullFn | None = None,
                 push_impl: PushFn | None = None,
-                use_kernels: bool = True, buckets: int = 2,
-                widths: list[int] | None = None,
+                use_kernels: bool = True,
                 direction: str = "auto", push_cap: int | None = None,
                 alpha: float = 4.0,
                 n_sources: int | None = None,
-                block: int | None = None,
                 counters: bool = False) -> Callable:
     """Build a jitted BFS callable ``f(src) -> levels`` for the named engine.
 
@@ -1149,18 +1113,14 @@ def make_engine(g: Graph, engine: str, *, sigma: int = 8,
     (core.policy.prepare, GraphSession) skip rebuilding the device BVSS;
     a mesh-sharded problem routes the BVSS engines through the
     ``shard_map``'d pipeline (DESIGN §2.4).
-    ``direction``/``push_cap``/``alpha``/``widths`` are the hybrid knobs
+    ``direction``/``push_cap``/``alpha`` are the hybrid knobs
     (DESIGN §2.8) of the blest/blest_lazy/multi_source engines;
     ``push_impl`` is the push-kernel fault seam.
     ``counters=True`` makes the single-device blest/blest_lazy engines
     return ``(levels, counts)`` (:func:`make_blest_bfs`).
     ``engine="multi_source"`` builds the batched BVSS bit-SpMM engine
     ``f(sources (S,)) -> levels (n, S)`` and requires ``n_sources``.
-    ``block`` is accepted for backwards compatibility and ignored: the fused
-    pipeline batches the whole compacted queue instead of slicing it into
-    sequential blocks.
     """
-    del block
     if counters and engine not in ("blest", "blest_lazy"):
         raise ConfigError(f"engine {engine!r} keeps no level counters")
     if engine == "dense_pull":
@@ -1179,15 +1139,13 @@ def make_engine(g: Graph, engine: str, *, sigma: int = 8,
                 raise ValueError("multi_source engine needs n_sources")
             return make_multi_source_bfs(g, n_sources, problem=problem,
                                          use_kernel=use_kernels,
-                                         buckets=buckets, widths=widths,
                                          direction=direction,
                                          push_cap=push_cap)
         if engine == "brs":
             return make_brs_bfs(problem)
         return make_blest_bfs(problem, lazy=(engine == "blest_lazy"),
                               pull_impl=pull_impl, push_impl=push_impl,
-                              use_kernels=use_kernels, buckets=buckets,
-                              widths=widths, direction=direction,
+                              use_kernels=use_kernels, direction=direction,
                               push_cap=push_cap, alpha=alpha,
                               counters=counters)
     raise ValueError(f"unknown engine {engine!r}")

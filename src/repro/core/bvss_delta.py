@@ -555,10 +555,7 @@ def _finish(prepared: PreparedBFS, g_ord2: Graph, bvss2: BVSS, problem2,
     result.  The engine rebuild recompiles (device arrays are closure
     constants of the jitted level loop) — the accepted cost of an epoch
     swap, amortised by batching updates (DESIGN §2.10)."""
-    tuned = prepared.tile_config.engine_kwargs() \
-        if prepared.tile_config is not None else {}
-    fn = build_engine(g_ord2, prepared.engine_name, bvss2, problem2, opts,
-                      tuned)
+    fn = build_engine(g_ord2, prepared.engine_name, bvss2, problem2, opts)
     return dataclasses.replace(
         prepared, graph=g_ord2, bvss=bvss2, problem=problem2,
         update_divergence=bvss2.update_divergence(), weights=w_new,

@@ -17,9 +17,9 @@ persistent kernel, §4.3) and the convergence test is on-device.
 
 ``step`` is one fused gather→pull→update pass.  Engines whose pull is a
 plain composition use :func:`compose_step`; the BLEST engines build a
-bucketed step instead (two statically-shaped queue widths selected by
-``lax.cond`` on the live VSS count — the XLA-compatible stand-in for the
-paper's dynamically-sized kernel launches).
+chunked step instead (``repro.core.bfs.pull_chunks``: as many fixed
+chunks of the queue as the live VSS count needs — the XLA-compatible
+stand-in for the paper's dynamically-sized kernel launches).
 
 The same skeleton is mesh-native (DESIGN §2.4): under ``shard_map`` the
 ``step`` stays purely local (each shard pulls/scatters its row block), the

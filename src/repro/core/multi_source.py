@@ -8,9 +8,10 @@ implementation, the hot path never materialises the O(n²/32) dense
 ``to_dense_bits`` adjacency — peak device memory scales with BVSS words.
 
 The level loop rides the same :class:`~repro.core.level_pipeline.LevelPipeline`
-skeleton as the single-source engines, and reuses their bucketed static-width
-queue: one compacted *union* queue of VSSs (a slice set is live if ANY source
-column's frontier touches it), the per-column frontier kept as packed words.
+skeleton as the single-source engines, and pulls its queue through their
+chunk loop (:func:`~repro.core.bfs.pull_chunks`): one compacted *union*
+queue of VSSs (a slice set is live if ANY source column's frontier touches
+it), the per-column frontier kept as packed words.
 
 :func:`make_ms_engine` exposes the jit-able building blocks (init / insert /
 requeue / one lock-step level) so GraphSession (``repro.serve``) can drive
@@ -39,9 +40,10 @@ operand), and one frontier-word all-gather per level refreshes it.  The
 σ channel shards exactly like the frontier bits: ``paths`` is a local
 ``(rows_per_shard, S)`` block, and each level's weighted pull consumes a
 per-level all-gather of the σ-frontier float values — the float twin of
-the frontier-word gather, hoisted OUT of the bucket ``cond`` (collectives
-inside device-varying branches would wedge the mesh).  The host-visible
-wave state then has a leading shard axis on every field.
+the frontier-word gather, hoisted OUT of the chunk loop (each shard runs
+its own trip count, and a collective inside it would wedge the mesh).
+The host-visible wave state then has a leading shard axis on every
+field.
 """
 from __future__ import annotations
 
@@ -53,9 +55,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bfs import (DIRECTIONS, BlestProblem, _frontier_bytes,
-                            _round_width, expand_push_queue, make_compactor,
-                            make_vertex_compactor, queue_widths,
-                            resolve_push_cap, select_width, selected_width)
+                            _round_width, chunk_ceil, chunk_count,
+                            expand_push_queue, make_compactor,
+                            make_vertex_compactor, pull_chunks,
+                            queue_capacity, resolve_push_cap)
 from repro.core.bvss import ShardedBVSSDevice
 from repro.core.level_pipeline import LevelPipeline, global_any, run_levels
 from repro.distributed.bfs_dist import frontier_all_gather
@@ -113,7 +116,7 @@ class MSState(NamedTuple):
                           #   per shard (each device's pull operand)
     Q: jnp.ndarray        # (qcap,) int32 union VSS queue, dummy-padded
                           #   sharded: (D, qcap), one queue per shard
-    count: jnp.ndarray    # int32 live VSS count (bucket pick; sharded (D,))
+    count: jnp.ndarray    # int32 live VSS count (chunks to pull; sharded (D,))
     col_lvl: jnp.ndarray  # (S,) int32 per-column BFS depth reached so far
                           #   sharded: (D, S) identical replicas
     cont: jnp.ndarray     # bool: any live VSS anywhere (mesh-global)
@@ -162,9 +165,8 @@ class MSEngine:
 
 
 def make_ms_engine(problem: BlestProblem, n_slots: int, *,
-                   use_kernel: bool = True, buckets: int = 2,
+                   use_kernel: bool = True,
                    track_sigma: bool = False,
-                   widths: list[int] | None = None,
                    direction: str = "auto", push_cap: int | None = None,
                    alpha: float = 4.0,
                    spmm_impl: Callable | None = None,
@@ -177,7 +179,7 @@ def make_ms_engine(problem: BlestProblem, n_slots: int, *,
     rides the generic sharded float path (per-level all-gather of the
     σ-frontier values, DESIGN §2.6).
 
-    ``direction`` / ``push_cap`` / ``alpha`` / ``widths`` are the
+    ``direction`` / ``push_cap`` / ``alpha`` are the
     direction-optimizing knobs of DESIGN §2.8, batched: the push branch
     compacts the UNION frontier (any column) into a vertex queue, expands
     each vertex into the ≤ R VSSs of its own slice set, and pushes
@@ -186,8 +188,8 @@ def make_ms_engine(problem: BlestProblem, n_slots: int, *,
     fault seam (``spmm_impl``).  ``track_sigma`` pins ``direction="pull"``
     (the σ channel's weighted twin has no push formulation; asking for
     forced push with σ tracking is a :class:`~repro.errors.ConfigError`).
-    ``widths`` overrides the bucketed pull ladder (autotuner injection
-    point); default is ``queue_widths(num_vss, buckets)``.
+    The pull runs :func:`~repro.core.bfs.pull_chunks` over the union
+    queue; auto mode prices it at ``chunk_ceil(count)`` slots.
 
     ``spmm_impl`` / ``spmm_w_impl`` / ``gather_impl`` are the documented
     FAULT SEAMS (DESIGN §2.7): engines capture their kernels in jitted
@@ -220,74 +222,76 @@ def make_ms_engine(problem: BlestProblem, n_slots: int, *,
     if p.mesh is not None:
         if p.is_2d:
             return _make_ms_engine_sharded_2d(p, n_slots, spmm=spmm,
-                                              buckets=buckets,
                                               spmm_w=spmm_w,
                                               track_sigma=track_sigma,
                                               gather=gather_impl,
-                                              widths=widths,
                                               direction=direction)
         return _make_ms_engine_sharded(p, n_slots, spmm=spmm,
-                                       buckets=buckets, spmm_w=spmm_w,
+                                       spmm_w=spmm_w,
                                        track_sigma=track_sigma,
-                                       gather=gather_impl, widths=widths,
+                                       gather=gather_impl,
                                        direction=direction,
                                        push_cap=push_cap, alpha=alpha)
     dev = p.dev
     sigma = p.sigma
     S = n_slots
     n, n_fwords = p.n, p.n_fwords
-    widths = list(widths) if widths is not None else \
-        queue_widths(p.num_vss, buckets)
-    qcap = widths[-1]
+    qcap = queue_capacity(p.num_vss)
     compact = make_compactor(dev, p.num_vss, qcap)
     all_sets = jnp.arange(p.n_sets, dtype=jnp.int32)
     n_pad = n_fwords * 32
     n_cols = p.n_sets * sigma  # padded column space (≥ n) for value gathers
     weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
 
-    def pull_update(state: MSState, width: int) -> MSState:
-        ids = jax.lax.slice_in_dim(state.Q, 0, width)
-        fb = _frontier_bytes(state.F, dev.virtual_to_real[ids], sigma)
-        counts = spmm(dev.masks[ids], fb, sigma=sigma)  # (w, spw, 32, S)
-        rows = dev.row_ids[ids].reshape(-1)
+    def pull_step(state: MSState) -> MSState:
         cand = (state.col_lvl + 1)[None, :]
-        upd = jnp.where(counts.reshape(-1, S) > 0, cand, INF
-                        ).astype(jnp.int32)
-        # eager scatter-min: an already-visited row keeps its smaller level;
-        # dummy rows land in the level sink (row n)
-        levels = state.levels.at[rows].min(upd)
+        acc = None
+        if track_sigma:
+            # σ channel (DESIGN §2.6): the weighted twin of the pull — the
+            # SAME queued tiles, contracted against the frontier's float
+            # path counts; rows discovered this level take the accumulated
+            # sum (Boolean counts gate discovery, so a converged column —
+            # whose frontier bits are gone but whose levels still match
+            # col_lvl — contributes nothing).  No chunk changes a row at
+            # col_lvl, so the operand is built once, before the loop.
+            xv = jnp.where(state.levels[:n] == state.col_lvl[None, :],
+                           state.paths, 0.0)
+            xv = jnp.concatenate(
+                [xv, jnp.zeros((n_cols - n, S), jnp.float32)])
+            acc = jnp.zeros((n + 1, S), jnp.float32)
+
+        def chunk(i, ids, carry):
+            levels, acc = carry
+            fb = _frontier_bytes(state.F, dev.virtual_to_real[ids], sigma)
+            counts = spmm(dev.masks[ids], fb, sigma=sigma)  # (C, spw, 32, S)
+            rows = dev.row_ids[ids].reshape(-1)
+            upd = jnp.where(counts.reshape(-1, S) > 0, cand, INF
+                            ).astype(jnp.int32)
+            # eager scatter-min: an already-visited row keeps its smaller
+            # level; dummy rows land in the level sink (row n)
+            levels = levels.at[rows].min(upd)
+            if track_sigma:
+                wv = bvss_spmm_w_local(dev.masks[ids],
+                                       dev.virtual_to_real[ids], xv,
+                                       sigma=sigma, impl=spmm_w)
+                acc = acc.at[rows].add(wv.reshape(-1, S))
+            return levels, acc
+
+        levels, acc = pull_chunks(state.Q, chunk_count(state.count), chunk,
+                                  (state.levels, acc))
         if not track_sigma:
             return state._replace(levels=levels)
-        # σ channel (DESIGN §2.6): the weighted twin of the pull above —
-        # the SAME queued tiles, contracted against the frontier's float
-        # path counts; rows discovered this level take the accumulated sum
-        # (Boolean counts gate discovery, so a converged column — whose
-        # frontier bits are gone but whose levels still match col_lvl —
-        # contributes nothing).
-        xv = jnp.where(levels[:n] == state.col_lvl[None, :],
-                       state.paths, 0.0)
-        xv = jnp.concatenate(
-            [xv, jnp.zeros((n_cols - n, S), jnp.float32)])
-        wv = bvss_spmm_w_local(dev.masks[ids], dev.virtual_to_real[ids],
-                               xv, sigma=sigma, impl=spmm_w)
-        acc = jnp.zeros((n + 1, S), jnp.float32).at[rows].add(
-            wv.reshape(-1, S))
         newly = levels[:n] == cand
         return state._replace(
             levels=levels, paths=jnp.where(newly, acc[:n], state.paths))
-
-    def pull_step(state: MSState) -> MSState:
-        return select_width(widths, state.count,
-                            lambda w: pull_update(state, w))
 
     pcap = resolve_push_cap(direction, push_cap, n)
     pqcap = _round_width(pcap)
     R = p.max_vss_per_set
     push_cost = pqcap * R
-    if direction == "pull" or (direction == "auto"
-                               and push_cost >= widths[-1]):
-        # push can never undercut even the full pull width: compile the
-        # pure pull step (same static bail as the single-source engines)
+    if direction == "pull" or (direction == "auto" and push_cost >= qcap):
+        # push can never undercut even the full queue: compile the pure
+        # pull step (same static bail as the single-source engines)
         step = pull_step
     else:
         compact_vertices = make_vertex_compactor(n_fwords, n, pqcap)
@@ -319,7 +323,7 @@ def make_ms_engine(problem: BlestProblem, n_slots: int, *,
                                     ).astype(jnp.float32)
                 use_push = ((ucount <= pcap)
                             & (jnp.int32(push_cost)
-                               < selected_width(widths, state.count))
+                               < chunk_ceil(state.count))
                             & (tbits * jnp.float32(alpha) <= unvisited))
                 return jax.lax.cond(use_push, push_update, pull_step,
                                     state)
@@ -513,8 +517,8 @@ class _MSLocals(NamedTuple):
     finalize: Callable
 
 
-def _make_ms_locals(p: BlestProblem, S: int, spmm, widths: list[int],
-                    qcap: int, *, spmm_w=None,
+def _make_ms_locals(p: BlestProblem, S: int, spmm, qcap: int, *,
+                    spmm_w=None,
                     track_sigma: bool = False,
                     gather: Callable | None = None,
                     direction: str = "pull",
@@ -529,15 +533,17 @@ def _make_ms_locals(p: BlestProblem, S: int, spmm, widths: list[int],
     pull contracts the shard's queued tiles against a per-level
     ``all_gather`` of every shard's σ-frontier float values — the float
     twin of the frontier-word gather in ``finalize``.  The gather is
-    hoisted OUT of the bucket ``cond`` (shards may pick different widths,
-    and a collective inside a device-varying branch wedges the mesh).
+    hoisted OUT of the chunk loop (each shard loops over its own live
+    count, and a collective inside a device-varying loop wedges the
+    mesh).
 
     ``direction`` / ``push_cap`` / ``alpha`` thread the direction
     heuristic (DESIGN §2.8): the frontier words are GLOBAL replicas, so
     every shard compacts the SAME union vertex queue and expands it
     through its OWN vertex→local-VSS maps — both cond branches stay
     collective-free (the heuristic's unvisited psum runs unconditionally
-    before the cond), so the per-shard width term may diverge safely.
+    before the cond), so the per-shard pull-cost term may diverge
+    safely.
     ``track_sigma`` callers must pass (or default to) ``direction="pull"``
     — the σ channel has no push twin."""
     if direction not in DIRECTIONS:
@@ -560,33 +566,41 @@ def _make_ms_locals(p: BlestProblem, S: int, spmm, widths: list[int],
     R = p.max_vss_per_set
     push_cost = pqcap * R
     pull_only = direction == "pull" or (direction == "auto"
-                                        and push_cost >= widths[-1])
+                                        and push_cost >= qcap)
 
     def locals_for(dev: ShardedBVSSDevice) -> _MSLocals:
         compact = make_compactor(dev, p.num_vss, qcap)
         compact_vertices = make_vertex_compactor(p.n_fwords, p.n, pqcap)
 
-        def pull_update(st: MSState, width: int,
-                        xg: jnp.ndarray | None) -> MSState:
-            ids = jax.lax.slice_in_dim(st.Q, 0, width)
-            fb = _frontier_bytes(st.F, dev.virtual_to_real[ids], sigma)
-            counts = spmm(dev.masks[ids], fb, sigma=sigma)
-            rows = dev.row_ids[ids].reshape(-1)   # LOCAL rows, dummy = rps
+        def pull_update(st: MSState, xg: jnp.ndarray | None) -> MSState:
             cand = (st.col_lvl + 1)[None, :]
-            upd = jnp.where(counts.reshape(-1, S) > 0, cand, INF
-                            ).astype(jnp.int32)
-            levels = st.levels.at[rows].min(upd)
+
+            def chunk(i, ids, carry):
+                levels, acc = carry
+                fb = _frontier_bytes(st.F, dev.virtual_to_real[ids], sigma)
+                counts = spmm(dev.masks[ids], fb, sigma=sigma)
+                rows = dev.row_ids[ids].reshape(-1)  # LOCAL, dummy = rps
+                upd = jnp.where(counts.reshape(-1, S) > 0, cand, INF
+                                ).astype(jnp.int32)
+                levels = levels.at[rows].min(upd)
+                if track_sigma:
+                    # σ channel: the weighted twin over the SAME queued
+                    # tiles, pulling from the gathered global frontier
+                    # values
+                    wv = bvss_spmm_w_local(dev.masks[ids],
+                                           dev.virtual_to_real[ids], xg,
+                                           sigma=sigma, impl=spmm_w)
+                    acc = acc.at[rows].add(wv.reshape(-1, S))
+                return levels, acc
+
+            acc = jnp.zeros((rps + 1, S), jnp.float32) if track_sigma \
+                else None
+            levels, acc = pull_chunks(st.Q, chunk_count(st.count), chunk,
+                                      (st.levels, acc))
             if not track_sigma:
                 return st._replace(levels=levels)
-            # σ channel: the weighted twin over the SAME queued tiles,
-            # pulling from the gathered global frontier values; only rows
-            # the Boolean counts discovered THIS level take the sum, so a
-            # converged column's stale values contribute nothing
-            wv = bvss_spmm_w_local(dev.masks[ids],
-                                   dev.virtual_to_real[ids], xg,
-                                   sigma=sigma, impl=spmm_w)
-            acc = jnp.zeros((rps + 1, S), jnp.float32).at[rows].add(
-                wv.reshape(-1, S))
+            # only rows the Boolean counts discovered THIS level take the
+            # sum, so a converged column's stale values contribute nothing
             newly = levels[:rps] == cand
             return st._replace(
                 levels=levels,
@@ -613,19 +627,16 @@ def _make_ms_locals(p: BlestProblem, S: int, spmm, widths: list[int],
                 # the one extra cross-device term of the float channel:
                 # all-gather the σ-frontier values (rows at depth col_lvl),
                 # mirroring finalize's frontier-word gather — BEFORE the
-                # bucket cond (no collectives inside its branches)
+                # direction cond and the chunk loop (no collectives in
+                # either)
                 xv = jnp.where(st.levels[:rps] == st.col_lvl[None, :],
                                st.paths, 0.0)
                 xg = jax.lax.all_gather(xv, axis, tiled=True)  # (n_pad, S)
             else:
                 xg = None
 
-            def pull_step(s: MSState) -> MSState:
-                return select_width(widths, s.count,
-                                    lambda w: pull_update(s, w, xg))
-
             if pull_only:
-                return pull_step(st)
+                return pull_update(st, xg)
             if direction == "push":
                 return push_update(st)
             ucount = jnp.sum(jax.lax.population_count(
@@ -634,14 +645,15 @@ def _make_ms_locals(p: BlestProblem, S: int, spmm, widths: list[int],
                             ).astype(jnp.float32)
             # unvisited is mesh-global (levels are local row blocks); the
             # psum runs on every shard BEFORE the branch, so the cond
-            # bodies stay collective-free even if the width term diverges
+            # bodies stay collective-free even if the pull-cost term
+            # diverges
             unvisited = jax.lax.psum(
                 jnp.sum(st.levels[:rps] == INF), axis).astype(jnp.float32)
             use_push = ((ucount <= pcap)
-                        & (jnp.int32(push_cost)
-                           < selected_width(widths, st.count))
+                        & (jnp.int32(push_cost) < chunk_ceil(st.count))
                         & (tbits * jnp.float32(alpha) <= unvisited))
-            return jax.lax.cond(use_push, push_update, pull_step, st)
+            return jax.lax.cond(use_push, push_update,
+                                lambda s: pull_update(s, xg), st)
 
         def requeue(st: MSState) -> MSState:
             # F is already the global replica: no gather needed here
@@ -742,8 +754,8 @@ def _make_ms_locals(p: BlestProblem, S: int, spmm, widths: list[int],
     return locals_for
 
 
-def _make_ms_locals_2d(p: BlestProblem, S: int, spmm, widths: list[int],
-                       qcap: int, *, spmm_w=None,
+def _make_ms_locals_2d(p: BlestProblem, S: int, spmm, qcap: int, *,
+                       spmm_w=None,
                        track_sigma: bool = False,
                        gather: Callable | None = None,
                        direction: str = "pull") -> Callable:
@@ -762,10 +774,11 @@ def _make_ms_locals_2d(p: BlestProblem, S: int, spmm, widths: list[int],
     evidence.  ``finalize`` then packs the newly array, keeps only this
     device's column segment of it, and butterfly-exchanges the segments
     over the ROW axis to rebuild next level's column-block frontier.  The
-    σ partial sums ride a float ``psum`` over the column axis, hoisted OUT
-    of the bucket ``cond`` (collectives inside device-varying branches
-    wedge the mesh), and the σ-frontier values are butterfly-gathered over
-    the row axis exactly like the frontier words.
+    σ partial sums ride a float ``psum`` over the column axis, after the
+    chunk loop (each device loops over its own live count, and a
+    collective inside a device-varying loop wedges the mesh), and the
+    σ-frontier values are butterfly-gathered over the row axis exactly
+    like the frontier words, before it.
 
     The 2-D partition is PULL-ONLY: the push formulation writes to remote
     row blocks, which the column-partitioned frontier cannot express
@@ -797,25 +810,28 @@ def _make_ms_locals_2d(p: BlestProblem, S: int, spmm, widths: list[int],
     def locals_for(dev: ShardedBVSSDevice) -> _MSLocals:
         compact = make_compactor(dev, p.num_vss, qcap)
 
-        def pull_partial(st: MSState, width: int, xg: jnp.ndarray | None):
-            """One bucket width's pull over block (i, j): returns the
-            PARTIAL per-column hit marks (rps+1, S) — row rps is the dummy
-            sink — plus the partial σ accumulator; nothing is committed
-            until the cross-column reduce."""
-            ids = jax.lax.slice_in_dim(st.Q, 0, width)
-            fb = _frontier_bytes(st.F, dev.virtual_to_real[ids], sigma)
-            counts = spmm(dev.masks[ids], fb, sigma=sigma)
-            rows = dev.row_ids[ids].reshape(-1)   # LOCAL rows, dummy = rps
-            hit = jnp.zeros((rps + 1, S), dtype=bool).at[rows].max(
-                counts.reshape(-1, S) > 0)
-            if not track_sigma:
-                return hit, None
-            wv = bvss_spmm_w_local(dev.masks[ids],
-                                   dev.virtual_to_real[ids], xg,
-                                   sigma=sigma, impl=spmm_w)
-            acc = jnp.zeros((rps + 1, S), jnp.float32).at[rows].add(
-                wv.reshape(-1, S))
-            return hit, acc
+        def pull_partial(st: MSState, xg: jnp.ndarray | None):
+            """The chunked pull over block (i, j): returns the PARTIAL
+            per-column hit marks (rps+1, S) — row rps is the dummy sink —
+            plus the partial σ accumulator; nothing is committed until
+            the cross-column reduce."""
+            def chunk(i, ids, carry):
+                hit, acc = carry
+                fb = _frontier_bytes(st.F, dev.virtual_to_real[ids], sigma)
+                counts = spmm(dev.masks[ids], fb, sigma=sigma)
+                rows = dev.row_ids[ids].reshape(-1)  # LOCAL, dummy = rps
+                hit = hit.at[rows].max(counts.reshape(-1, S) > 0)
+                if track_sigma:
+                    wv = bvss_spmm_w_local(dev.masks[ids],
+                                           dev.virtual_to_real[ids], xg,
+                                           sigma=sigma, impl=spmm_w)
+                    acc = acc.at[rows].add(wv.reshape(-1, S))
+                return hit, acc
+
+            acc = jnp.zeros((rps + 1, S), jnp.float32) if track_sigma \
+                else None
+            return pull_chunks(st.Q, chunk_count(st.count), chunk,
+                               (jnp.zeros((rps + 1, S), dtype=bool), acc))
 
         def step(st: MSState) -> MSState:
             j = jax.lax.axis_index(cax)
@@ -824,7 +840,7 @@ def _make_ms_locals_2d(p: BlestProblem, S: int, spmm, widths: list[int],
                 # segment of its row block's values, butterfly-gathered
                 # over the ROW axis into the (n_loc, S) pull operand —
                 # the float twin of finalize's frontier-word exchange,
-                # hoisted BEFORE the bucket cond
+                # hoisted BEFORE the chunk loop
                 xv = jnp.where(st.levels[:rps] == st.col_lvl[None, :],
                                st.paths, 0.0)
                 seg = jax.lax.dynamic_slice_in_dim(xv, j * cpb, cpb, axis=0)
@@ -834,8 +850,7 @@ def _make_ms_locals_2d(p: BlestProblem, S: int, spmm, widths: list[int],
                         [xg, jnp.zeros((n_cols - n_loc, S), jnp.float32)])
             else:
                 xg = None
-            hit, acc = select_width(widths, st.count,
-                                    lambda w: pull_partial(st, w, xg))
+            hit, acc = pull_partial(st, xg)
             # cross-column combine: pack the partial hits per column, OR
             # them across the column axis, and only then commit levels —
             # every device in mesh row i sees the SAME full-row-block hits
@@ -957,10 +972,9 @@ def _make_ms_locals_2d(p: BlestProblem, S: int, spmm, widths: list[int],
 
 
 def _make_ms_engine_sharded(p: BlestProblem, n_slots: int, *, spmm,
-                            buckets: int, spmm_w=None,
+                            spmm_w=None,
                             track_sigma: bool = False,
                             gather: Callable | None = None,
-                            widths: list[int] | None = None,
                             direction: str = "auto",
                             push_cap: int | None = None,
                             alpha: float = 4.0) -> MSEngine:
@@ -974,10 +988,8 @@ def _make_ms_engine_sharded(p: BlestProblem, n_slots: int, *, spmm,
     mesh, axis = p.mesh, p.axis
     D, rps = p.n_shards, p.rows_per_shard
     S = n_slots
-    widths = list(widths) if widths is not None else \
-        queue_widths(p.num_vss, buckets)
-    qcap = widths[-1]
-    locals_for = _make_ms_locals(p, S, spmm, widths, qcap, spmm_w=spmm_w,
+    qcap = queue_capacity(p.num_vss)
+    locals_for = _make_ms_locals(p, S, spmm, qcap, spmm_w=spmm_w,
                                  track_sigma=track_sigma, gather=gather,
                                  direction=direction, push_cap=push_cap,
                                  alpha=alpha)
@@ -1070,10 +1082,9 @@ def _make_ms_engine_sharded(p: BlestProblem, n_slots: int, *, spmm,
 
 
 def _make_ms_engine_sharded_2d(p: BlestProblem, n_slots: int, *, spmm,
-                               buckets: int, spmm_w=None,
+                               spmm_w=None,
                                track_sigma: bool = False,
                                gather: Callable | None = None,
-                               widths: list[int] | None = None,
                                direction: str = "auto") -> MSEngine:
     """The 2-D twin of :func:`_make_ms_engine_sharded`: same host surface,
     R·C device blocks stacked row-major on every leading dim (block
@@ -1091,11 +1102,8 @@ def _make_ms_engine_sharded_2d(p: BlestProblem, n_slots: int, *, spmm,
     R, C, rps = p.n_shards, p.n_col_shards, p.rows_per_shard
     D = R * C
     S = n_slots
-    widths = list(widths) if widths is not None else \
-        queue_widths(p.num_vss, buckets)
-    qcap = widths[-1]
-    locals_for = _make_ms_locals_2d(p, S, spmm, widths, qcap,
-                                    spmm_w=spmm_w,
+    qcap = queue_capacity(p.num_vss)
+    locals_for = _make_ms_locals_2d(p, S, spmm, qcap, spmm_w=spmm_w,
                                     track_sigma=track_sigma, gather=gather,
                                     direction=direction)
 
@@ -1193,16 +1201,14 @@ def make_multi_source_bfs(g: Graph | None, n_sources: int, *,
                           use_kernel: bool = True,
                           max_levels: int | None = None,
                           bvss=None, problem: BlestProblem | None = None,
-                          buckets: int = 2,
-                          widths: list[int] | None = None,
                           direction: str = "auto",
                           push_cap: int | None = None,
                           alpha: float = 4.0) -> Callable:
     """Build jitted ``f(sources (S,) i32) -> levels (n, S) i32`` with the
     whole level loop fused on device (fixed source cohort).  A sharded
     ``problem`` runs the loop as one ``shard_map``'d ``while_loop``.
-    ``widths`` / ``direction`` / ``push_cap`` / ``alpha`` are the
-    direction-optimizing knobs (DESIGN §2.8; see :func:`make_ms_engine`)."""
+    ``direction`` / ``push_cap`` / ``alpha`` are the direction-optimizing
+    knobs (DESIGN §2.8; see :func:`make_ms_engine`)."""
     if problem is None:
         if bvss is None:
             from repro.core.bvss import build_bvss
@@ -1212,14 +1218,12 @@ def make_multi_source_bfs(g: Graph | None, n_sources: int, *,
     if problem.mesh is not None:
         if problem.is_2d:
             return _make_multi_source_bfs_sharded_2d(
-                problem, n_sources, use_kernel=use_kernel, buckets=buckets,
-                max_lv=max_lv, widths=widths, direction=direction)
+                problem, n_sources, use_kernel=use_kernel, max_lv=max_lv,
+                direction=direction)
         return _make_multi_source_bfs_sharded(
-            problem, n_sources, use_kernel=use_kernel, buckets=buckets,
-            max_lv=max_lv, widths=widths, direction=direction,
-            push_cap=push_cap, alpha=alpha)
+            problem, n_sources, use_kernel=use_kernel, max_lv=max_lv,
+            direction=direction, push_cap=push_cap, alpha=alpha)
     eng = make_ms_engine(problem, n_sources, use_kernel=use_kernel,
-                         buckets=buckets, widths=widths,
                          direction=direction, push_cap=push_cap,
                          alpha=alpha)
     step, finalize = eng.step, eng.finalize
@@ -1236,9 +1240,7 @@ def make_multi_source_bfs(g: Graph | None, n_sources: int, *,
 
 
 def _make_multi_source_bfs_sharded(p: BlestProblem, n_sources: int, *,
-                                   use_kernel: bool, buckets: int,
-                                   max_lv: int,
-                                   widths: list[int] | None = None,
+                                   use_kernel: bool, max_lv: int,
                                    direction: str = "auto",
                                    push_cap: int | None = None,
                                    alpha: float = 4.0) -> Callable:
@@ -1252,11 +1254,8 @@ def _make_multi_source_bfs_sharded(p: BlestProblem, n_sources: int, *,
     mesh, axis = p.mesh, p.axis
     rps = p.rows_per_shard
     S = n_sources
-    widths = list(widths) if widths is not None else \
-        queue_widths(p.num_vss, buckets)
-    qcap = widths[-1]
     spmm = bvss_spmm if use_kernel else bvss_spmm_ref
-    locals_for = _make_ms_locals(p, S, spmm, widths, qcap,
+    locals_for = _make_ms_locals(p, S, spmm, queue_capacity(p.num_vss),
                                  direction=direction, push_cap=push_cap,
                                  alpha=alpha)
 
@@ -1283,9 +1282,7 @@ def _make_multi_source_bfs_sharded(p: BlestProblem, n_sources: int, *,
 
 
 def _make_multi_source_bfs_sharded_2d(p: BlestProblem, n_sources: int, *,
-                                      use_kernel: bool, buckets: int,
-                                      max_lv: int,
-                                      widths: list[int] | None = None,
+                                      use_kernel: bool, max_lv: int,
                                       direction: str = "auto") -> Callable:
     """Fixed-cohort multi-source on the 2-D mesh: the same 2-D local
     step/finalize as the serving surface, fused into one ``shard_map``'d
@@ -1299,11 +1296,8 @@ def _make_multi_source_bfs_sharded_2d(p: BlestProblem, n_sources: int, *,
     rax, cax = p.axis, p.col_axis
     R, C, rps = p.n_shards, p.n_col_shards, p.rows_per_shard
     S = n_sources
-    widths = list(widths) if widths is not None else \
-        queue_widths(p.num_vss, buckets)
-    qcap = widths[-1]
     spmm = bvss_spmm if use_kernel else bvss_spmm_ref
-    locals_for = _make_ms_locals_2d(p, S, spmm, widths, qcap,
+    locals_for = _make_ms_locals_2d(p, S, spmm, queue_capacity(p.num_vss),
                                     direction=direction)
 
     def local_loop(masks, row_ids, v2r, vstart, vend, sources):

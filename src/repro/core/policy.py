@@ -30,7 +30,6 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro import tracing
-from repro.core.autotune import TileConfig
 from repro.core.bfs import BlestProblem, level_counter_names, make_engine
 from repro.core.bvss import (BVSS, build_bvss, build_sharded_bvss,
                              build_sharded_weight_plane, build_weight_plane,
@@ -52,7 +51,7 @@ class PrepareOptions:
 
     The public way to configure a preparation::
 
-        prepare(g, options=PrepareOptions(sigma=4, autotune=True))
+        prepare(g, options=PrepareOptions(sigma=4, direction="pull"))
 
     The options ride along on ``PreparedBFS.options`` so downstream
     maintenance (:func:`repro.core.bvss_delta.apply_edge_updates`) can
@@ -69,9 +68,7 @@ class PrepareOptions:
     order: bool = True                    # run the ordering pre-pass
     engine: str | None = None             # explicit engine override
     use_kernels: bool = True              # Pallas kernels vs pure-jnp twins
-    buckets: int = 2                      # queue-width ladder graduations
     direction: str = "auto"               # push/pull hybrid mode
-    autotune: bool = False                # measure hybrid knobs per backend
     push_impl: Callable | None = None     # push-kernel fault seam
     mesh: Mesh | None = None              # row-shard over this device mesh
     mesh_axis: str = "data"               # row axis name of the mesh
@@ -100,11 +97,6 @@ class PreparedBFS:
     # weighted-verb operand, +inf dummy row appended); None = unweighted
     weights: np.ndarray | None = None
     wplane: "object | None" = None
-    # winning hybrid knobs when prepared with autotune=True (DESIGN §2.8);
-    # None = defaults were used.  ``tile_config.source == "cached"`` means
-    # this prepare() re-used an earlier measurement (zero tuning
-    # dispatches) — the memoisation contract tests assert on it
-    tile_config: "TileConfig | None" = None
     # the exact knobs this preparation ran with — incremental maintenance
     # (core/bvss_delta.py) rebuilds engines / re-prepares through them
     options: "PrepareOptions | None" = None
@@ -222,11 +214,6 @@ def prepare(g: Graph, options: PrepareOptions | None = None,
       ``push_impl`` overrides the push kernel — the single-source push
       fault seam (DESIGN §2.7), threaded through by the serving tier's
       :class:`~repro.serve.faults.FaultPlan`.
-    * ``autotune=True`` measures the hybrid's static knobs — pull-queue
-      ladder, push cap — for this backend and graph class before the
-      engine build (``core.autotune``; memoised, so repeat preparations
-      of the same class perform zero extra timing dispatches) and records
-      the winner on ``PreparedBFS.tile_config``.
     * ``mesh`` row-shards the problem over ``mesh_axis`` and builds the
       mesh-native engine (DESIGN §2.4): the policy decisions (ordering,
       update scheme) still come from the global BVSS, the level loop runs
@@ -269,20 +256,12 @@ def prepare(g: Graph, options: PrepareOptions | None = None,
             with tracing.span("repro.prepare.weights"):
                 wplane = _build_weight_plane(g_ord, w_ord, sb, o)
         with tracing.span("repro.prepare.engine"):
-            tile_config: TileConfig | None = None
-            tuned_kwargs: dict = {}
-            if o.autotune and engine_name in BVSS_ENGINES \
-                    and problem is not None:
-                from repro.core.autotune import tune
-                tile_config = tune(problem, use_kernels=o.use_kernels)
-                tuned_kwargs = tile_config.engine_kwargs()
-            fn = build_engine(g_ord, engine_name, bvss, problem, o,
-                              tuned_kwargs)
+            fn = build_engine(g_ord, engine_name, bvss, problem, o)
     return PreparedBFS(graph=g_ord, perm=perm, inv=inv, ordering=kind,
                        engine_name=engine_name, bvss=bvss, problem=problem,
                        update_divergence=bvss.update_divergence(),
                        mesh=o.mesh, weights=w_ord, wplane=wplane,
-                       tile_config=tile_config, options=o, _fn=fn)
+                       options=o, _fn=fn)
 
 
 def _order(g: Graph, o: PrepareOptions):
@@ -361,14 +340,13 @@ def _build_weight_plane(g_ord: Graph, w_ord, sb, o: PrepareOptions):
 
 
 def build_engine(g_ord: Graph, engine_name: str, bvss: BVSS, problem,
-                 o: PrepareOptions, tuned_kwargs: dict) -> Callable:
+                 o: PrepareOptions) -> Callable:
     """The prepared single-source engine; the single-device blest and
     blest_lazy engines also return their level counters."""
     counted = engine_name in ("blest", "blest_lazy") and o.mesh is None
     return make_engine(g_ord, engine_name, bvss=bvss, problem=problem,
-                       use_kernels=o.use_kernels, buckets=o.buckets,
-                       direction=o.direction, push_impl=o.push_impl,
-                       counters=counted, **tuned_kwargs)
+                       use_kernels=o.use_kernels, direction=o.direction,
+                       push_impl=o.push_impl, counters=counted)
 
 
 def parents_from_levels(g: Graph, levels: np.ndarray) -> np.ndarray:

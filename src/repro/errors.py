@@ -41,10 +41,10 @@ class GraphValidationError(BlestError, ValueError):
 
 
 class ConfigError(BlestError, ValueError):
-    """An engine or tuning configuration is unusable (e.g. a bucket count
-    the queue-width ladder cannot honour).  Raised instead of silently
-    degrading to a nearby valid configuration — a silent fallback would
-    make autotuner search results lie about what actually ran."""
+    """An engine configuration is unusable (e.g. an unknown direction
+    mode, or forced push on a 2-D mesh).  Raised instead of silently
+    degrading to a nearby valid configuration, which would hide what
+    actually ran."""
 
 
 class AdmissionError(BlestError):

@@ -98,7 +98,7 @@ class GraphSession:
                  w: int = 512, seed: int = 0,
                  lazy_threshold: float | None = None, order: bool = True,
                  engine: str | None = None, use_kernel: bool = True,
-                 direction: str = "auto", autotune: bool = False,
+                 direction: str = "auto",
                  max_steps: int | None = None, mesh: Mesh | None = None,
                  mesh_axis: str = "data", weights=None,
                  fault_plan: FaultPlan | None = None):
@@ -106,12 +106,12 @@ class GraphSession:
             options = PrepareOptions(
                 sigma=sigma, w=w, seed=seed, lazy_threshold=lazy_threshold,
                 order=order, engine=engine, use_kernels=use_kernel,
-                direction=direction, autotune=autotune, mesh=mesh,
+                direction=direction, mesh=mesh,
                 mesh_axis=mesh_axis, weights=weights)
         elif (sigma, w, seed, lazy_threshold, order, engine, use_kernel,
-              direction, autotune, mesh, mesh_axis, weights) != \
-                (8, 512, 0, None, True, None, True, "auto", False, None,
-                 "data", None):
+              direction, mesh, mesh_axis, weights) != \
+                (8, 512, 0, None, True, None, True, "auto", None, "data",
+                 None):
             raise ConfigError(
                 "GraphSession takes EITHER options=PrepareOptions(...) or "
                 "the per-knob keywords, not both")

@@ -11,7 +11,6 @@ import pytest
 from repro.errors import ConfigError
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
 
 
 @pytest.fixture
@@ -35,15 +34,6 @@ def test_launcher_refuses_missing_chips(one_chip_backend):
     with pytest.raises(ConfigError, match="requested 4 devices.*tpu "
                                           "backend has 1"):
         ensure_devices(4, [])
-
-
-@pytest.mark.parametrize("which", ["run", "run_2d"])
-def test_bench_dist_refuses_missing_chips(one_chip_backend, which):
-    from benchmarks import bench_dist
-    call = {"run": lambda: bench_dist.run(scale=6, devices=4),
-            "run_2d": lambda: bench_dist.run_2d(scale=6)}[which]
-    with pytest.raises(ConfigError, match="devices, the tpu backend has 1"):
-        call()
 
 
 def test_launcher_simulates_devices_only_on_cpu():

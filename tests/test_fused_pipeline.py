@@ -74,20 +74,6 @@ def test_kernel_and_jnp_paths_agree(engine):
                                       reference_bfs(g, src))
 
 
-@pytest.mark.parametrize("engine", ["blest", "blest_lazy"])
-def test_bucketed_pull_matches_single_bucket(engine):
-    """The 2-bucket cond-selected queue width must be invisible in the
-    output; a high-diameter grid exercises the small bucket, an rmat the
-    full one."""
-    for g in (gen.grid2d(19, 23), gen.rmat(8, 8, seed=5)):
-        f2 = make_engine(g, engine, buckets=2)
-        f1 = make_engine(g, engine, buckets=1)
-        for src in (0, g.n - 1):
-            ref = reference_bfs(g, src)
-            np.testing.assert_array_equal(np.asarray(f2(src)), ref)
-            np.testing.assert_array_equal(np.asarray(f1(src)), ref)
-
-
 @settings(max_examples=10, deadline=None)
 @given(n=st.integers(2, 120), m=st.integers(0, 500),
        seed=st.integers(0, 10_000),

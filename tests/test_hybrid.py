@@ -1,7 +1,7 @@
 """Direction-optimizing push/pull hybrid (DESIGN §2.8): push-kernel
 parity, oracle parity of levels AND parents in all three direction modes
-across the single-source / lazy / multi-source engines, sharded parity on
-{1, 2, 8} devices, and the autotuner's memoisation contract."""
+across the single-source / lazy / multi-source engines, and sharded parity
+on {1, 2, 8} devices."""
 import os
 import subprocess
 import sys
@@ -9,14 +9,12 @@ import sys
 import numpy as np
 import pytest
 
-from repro import tracing
 from repro.core import reference_bfs
-from repro.core.autotune import TileConfig, clear_cache, tune
 from repro.core.bfs import (DEFAULT_PUSH_CAP, BlestProblem, _round_width,
-                            chunk_ceil, make_engine, queue_widths)
+                            chunk_ceil, make_engine, queue_capacity)
 from repro.core.bvss import build_bvss
 from repro.core.multi_source import make_multi_source_bfs
-from repro.core.policy import parents_from_levels, prepare
+from repro.core.policy import parents_from_levels
 from repro.errors import ConfigError
 from repro.graphs import generators as gen
 from repro.kernels import push_vss_kernel
@@ -134,7 +132,7 @@ def test_auto_mode_genuinely_flips():
     g = FLIP_GRAPH
     b = build_bvss(g)
     p = BlestProblem.build(b)
-    qcap = chunk_ceil(queue_widths(p.num_vss, 2)[-1])
+    qcap = queue_capacity(p.num_vss)
     pqcap = _round_width(DEFAULT_PUSH_CAP)
     push_cost = pqcap * p.max_vss_per_set
     assert push_cost < qcap, "static bail: graph cannot flip"
@@ -175,23 +173,6 @@ def test_track_sigma_rejects_forced_push():
     with pytest.raises(ConfigError):
         make_ms_engine(p, 2, use_kernel=False, track_sigma=True,
                        direction="push")
-
-
-def test_bad_buckets_is_config_error():
-    with pytest.raises(ConfigError):
-        queue_widths(512, 0)
-
-
-def test_queue_widths_ladder_shape():
-    """Graduated ladder: ascending, deduplicated, full width last,
-    PULL_TILE floor respected."""
-    for num_vss, buckets in [(512, 2), (2048, 3), (2048, 4), (100, 4),
-                             (60000, 4), (1, 1)]:
-        ws = queue_widths(num_vss, buckets)
-        assert ws == sorted(set(ws))
-        assert ws[-1] == _round_width(num_vss)
-        assert all(w >= 128 for w in ws)
-        assert len(ws) <= buckets
 
 
 # ---------------------------------------------------------------------------
@@ -247,60 +228,3 @@ for direction in ("pull", "push", "auto"):
             (direction, int(s))
 print("ok")
 """, n_devices=n_devices)
-
-
-# ---------------------------------------------------------------------------
-# autotuner: memoisation contract + escape hatch
-# ---------------------------------------------------------------------------
-@pytest.fixture
-def fresh_tuner():
-    clear_cache()
-    yield
-    clear_cache()
-
-
-def test_autotune_prepare_caches_winning_config(fresh_tuner):
-    """Second prepare() of the same (backend, σ, size-class) performs
-    ZERO additional tuning dispatches and re-serves the same knobs."""
-    g1, g2 = gen.grid2d(32, 32), gen.grid2d(31, 33)
-    pb1 = prepare(g1, engine="blest", use_kernels=False, autotune=True)
-    assert isinstance(pb1.tile_config, TileConfig)
-    assert pb1.tile_config.source == "tuned"
-    runs_after_first = tracing.counter("autotune.tune_runs")
-    pb2 = prepare(g2, engine="blest", use_kernels=False, autotune=True)
-    assert pb2.tile_config.source == "cached"
-    assert tracing.counter("autotune.tune_runs") == runs_after_first, "re-tuned a cached class"
-    assert pb2.tile_config.pull_widths == pb1.tile_config.pull_widths
-    assert pb2.tile_config.push_cap == pb1.tile_config.push_cap
-    # the tuned engine still answers correctly
-    for pb, g in ((pb1, g1), (pb2, g2)):
-        np.testing.assert_array_equal(pb.levels(0), reference_bfs(g, 0))
-
-
-def test_autotune_env_escape_hatch(fresh_tuner, monkeypatch):
-    monkeypatch.setenv("BLEST_AUTOTUNE", "0")
-    runs0 = tracing.counter("autotune.tune_runs")
-    p = BlestProblem.build(build_bvss(gen.grid2d(16, 16)))
-    cfg = tune(p, use_kernels=False)
-    assert cfg.source == "disabled"
-    assert tracing.counter("autotune.tune_runs") == runs0, "BLEST_AUTOTUNE=0 still measured"
-    assert cfg.pull_widths == tuple(queue_widths(p.num_vss, 2))
-    assert cfg.push_cap == DEFAULT_PUSH_CAP
-
-
-def test_autotune_off_by_default():
-    pb = prepare(gen.path(64), engine="blest", use_kernels=False)
-    assert pb.tile_config is None
-
-
-def test_autotune_rejects_bad_reps(fresh_tuner):
-    p = BlestProblem.build(build_bvss(gen.path(64)))
-    with pytest.raises(ConfigError):
-        tune(p, use_kernels=False, reps=0)
-
-
-def test_tile_config_engine_kwargs_roundtrip():
-    cfg = TileConfig(pull_widths=(128, 512), push_cap=256, alpha=4.0,
-                     source="tuned")
-    kw = cfg.engine_kwargs()
-    assert kw == {"widths": [128, 512], "push_cap": 256, "alpha": 4.0}

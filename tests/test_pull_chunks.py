@@ -12,7 +12,7 @@ import pytest
 
 from repro.core import reference_bfs
 from repro.core.bfs import (INF, PULL_CHUNK, BlestProblem, make_blest_bfs,
-                            queue_widths)
+                            queue_capacity)
 from repro.core.bvss import build_bvss
 from repro.graphs import from_edges
 
@@ -71,7 +71,7 @@ def case(request):
         assert max(counts) == b.num_vss
     else:
         assert k in counts or (k == 0 and counts == [])
-    assert queue_widths(b.num_vss, 2)[-1] % C, "queue already whole chunks"
+    assert queue_capacity(b.num_vss) > b.num_vss, "no dummy-padded tail"
     return request.param, g, b, want, counts
 
 
